@@ -14,10 +14,13 @@ measures and the ``bench_sharding_gate`` in run.py --quick asserts:
   vs one per family on the grouped path, without losing bitwise
   equality.
 
-Mesh sizes > 1 need the host platform split into virtual devices
-BEFORE jax initializes, so this script re-execs itself with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` appended; the
-gate just runs the script as a subprocess and reads the JSON back.
+On the CPU platform, mesh sizes > 1 need the host split into virtual
+devices BEFORE jax initializes, so this script re-execs itself with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` appended.  On an
+accelerator it runs in-process over the mesh sizes the present devices
+allow: a chip belongs to one process, and a child started after the
+parent touched jax could not get it.  ``gate_record`` makes that choice
+for the gate.
 Throughput context: on a multi-core (or genuinely multi-device) host
 the lane shards run concurrently and the curve scales; CI containers
 pinned to one core still must stay within noise of the unsharded path
@@ -124,20 +127,31 @@ def run_sharding(T: int, n: int, k: int, policies=POLICIES,
             best["lanes_per_s"] / max(unsharded_lps, 1e-9), 3))
 
 
-def _child(args) -> None:
-    if args.gate:
-        rec, key = run_sharding(T=96, n=256, k=32), "gate"
+def record(gate: bool, path: str) -> dict:
+    """Measure at gate or full scale over every mesh size the present
+    devices allow, and merge the record into the JSON file ``path``
+    under "gate" / "full"."""
+    import jax
+
+    meshes = tuple(d for d in MESH_SIZES if d <= jax.device_count())
+    if gate:
+        rec, key = run_sharding(T=96, n=256, k=32, mesh_sizes=meshes), "gate"
     else:
-        rec, key = run_sharding(T=240, n=512, k=64), "full"
+        rec, key = run_sharding(T=240, n=512, k=64,
+                                mesh_sizes=meshes), "full"
     try:
-        with open(args.out) as f:
+        with open(path) as f:
             out = json.load(f)
     except (OSError, ValueError):
         out = {}
     out[key] = rec
-    with open(args.out, "w") as f:
+    with open(path, "w") as f:
         json.dump(out, f, indent=1)
         f.write("\n")
+    return rec
+
+
+def _report(rec: dict) -> None:
     print(f"lanes={rec['lanes']} devices={rec['devices']} "
           f"union={rec['union']['dispatches']} dispatch(es) "
           f"(grouped {rec['grouped']['dispatches']}) "
@@ -151,23 +165,49 @@ def _child(args) -> None:
           f"mesh={rec['best_mesh']}")
 
 
+def _forced_devices_child(argv, **kwargs) -> subprocess.CompletedProcess:
+    """Run this script in a child whose host platform is split into 8
+    virtual devices (the flag must be set before jax initializes)."""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + _FORCE_FLAG).strip()
+    env[_CHILD_ENV] = "1"
+    return subprocess.run([sys.executable, os.path.abspath(__file__)]
+                          + list(argv), env=env, **kwargs)
+
+
+def gate_record(path: str = "BENCH_sharding.json") -> dict:
+    """The gate-scale record, also merged into ``path``: on the CPU
+    platform measured in a forced-8-device child, on an accelerator in
+    this process.  Raises RuntimeError if the child wrote no record."""
+    import jax
+
+    if jax.default_backend() != "cpu":
+        return record(gate=True, path=path)
+    proc = _forced_devices_child(["--gate", "--out", path],
+                                 capture_output=True, text=True)
+    try:
+        with open(path) as f:
+            rec = json.load(f)["gate"] if proc.returncode == 0 else None
+    except (OSError, ValueError, KeyError):
+        rec = None
+    if not rec:
+        tail = (proc.stderr or proc.stdout or "")[-300:]
+        raise RuntimeError(f"rc={proc.returncode}: {tail!r}")
+    return rec
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="BENCH_sharding.json")
     ap.add_argument("--gate", action="store_true",
                     help="gate scale (CI); default is the full record")
     args = ap.parse_args()
-    if os.environ.get(_CHILD_ENV) == "1":
-        _child(args)
+    import jax
+
+    if os.environ.get(_CHILD_ENV) == "1" or jax.default_backend() != "cpu":
+        _report(record(args.gate, args.out))
         return
-    # re-exec with the host platform split into 8 virtual devices; the
-    # flag must be set before jax initializes anywhere in the process.
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + _FORCE_FLAG).strip()
-    env[_CHILD_ENV] = "1"
-    raise SystemExit(subprocess.run(
-        [sys.executable, os.path.abspath(__file__)] + sys.argv[1:],
-        env=env).returncode)
+    raise SystemExit(_forced_devices_child(sys.argv[1:]).returncode)
 
 
 if __name__ == "__main__":

@@ -584,36 +584,24 @@ def bench_sharding_gate():
     """Quick-gate for the mesh sweep fabric (simulator/fabric.py, bench in
     benchmarks/bench_sharding.py): the mixed-family panel must (a) be
     bitwise-identical unsharded and at every mesh size in {1, 2, 4, 8}
-    (run in a subprocess — splitting the host into virtual devices needs
-    XLA_FLAGS set before jax initializes), (b) compile to exactly ONE
-    union dispatch where the grouped path needs one per family, and (c)
-    keep sharded throughput within noise of the unsharded path (>= 0.5x
-    on a single-core CI host; on real multi-device hosts the curve
-    scales).  Records the curve in BENCH_sharding.json under "gate"
+    the devices allow (``bench_sharding.gate_record``: on CPU over
+    virtual devices in a child, on an accelerator in this process), (b)
+    compile to
+    exactly ONE union dispatch where the grouped path needs one per
+    family, and (c) keep sharded throughput within noise of the unsharded
+    path (>= 0.5x on a single-core CI host; on real multi-device hosts
+    the curve scales).  Records the curve in BENCH_sharding.json under "gate"
     (benchmarks/bench_sharding.py writes the full-scale record)."""
-    import json
-    import os
-    import subprocess
-    import sys
+    from benchmarks import bench_sharding
 
-    script = os.path.join(os.path.dirname(__file__), "bench_sharding.py")
     t0 = time.time()
-    proc = subprocess.run([sys.executable, script, "--gate"],
-                          capture_output=True, text=True)
-    wall = time.time() - t0
-    rec = {}
-    if proc.returncode == 0:
-        try:
-            with open("BENCH_sharding.json") as f:
-                rec = json.load(f)["gate"]
-        except (OSError, ValueError, KeyError):
-            rec = {}
-    if not rec:
-        tail = (proc.stderr or proc.stdout or "")[-300:]
-        claim("mesh fabric gate subprocess produced a record",
-              f"rc={proc.returncode}: {tail!r}", "BENCH_sharding.json gate "
-              "record written", False)
+    try:
+        rec = bench_sharding.gate_record("BENCH_sharding.json")
+    except RuntimeError as e:
+        claim("mesh fabric gate produced a record", str(e),
+              "BENCH_sharding.json gate record written", False)
         return
+    wall = time.time() - t0
     curve = {c["mesh"]: c["lanes_per_s"] for c in rec["mesh_curve"]}
     emit("sharding_gate", wall * 1e6,
          f"lanes={rec['lanes']};devices={rec['devices']};"

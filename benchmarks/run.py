@@ -17,6 +17,8 @@ def main() -> None:
     args, _ = ap.parse_known_args()
 
     from benchmarks import common, framework, paper_tables as pt
+    from repro.utils.compilation import setup_compile_cache
+    setup_compile_cache()
     common.header()
     if not args.quick:
         pt.bench_tuning_study()

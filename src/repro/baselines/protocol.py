@@ -70,6 +70,7 @@ import numpy as np
 
 from repro.baselines.base import Policy
 from repro.simulator.simjax import DST_BELOW
+from repro.utils.topk import top_k
 
 SENTINEL = -1
 
@@ -88,9 +89,9 @@ def ranked_take(key, mask, pad: int, limit=None):
     pad = max(1, min(pad, n))
     # top_k, not argsort: XLA's generic sort is ~50x slower on CPU at
     # simulator scale, and top_k's tie rule (lower index first) matches a
-    # stable ascending argsort exactly.
-    _, order = jax.lax.top_k(jnp.where(mask, -key.astype(jnp.float32),
-                                       -jnp.inf), pad)
+    # stable ascending argsort exactly (utils.topk keeps the rule on TPU).
+    _, order = top_k(jnp.where(mask, -key.astype(jnp.float32), -jnp.inf),
+                     pad)
     order = order.astype(jnp.int32)
     count = mask.sum().astype(jnp.int32)
     if limit is not None:

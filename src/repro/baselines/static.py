@@ -2,12 +2,12 @@
 an oracle upper bound (true-count top-k, instant migration)."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.baselines.protocol import (LegacyPolicyAdapter, PolicySpec,
                                       ranked_take)
 from repro.utils.pytree import pytree_dataclass
+from repro.utils.topk import top_k
 
 
 @pytree_dataclass
@@ -71,7 +71,7 @@ class OracleSpec(PolicySpec):
 
     def policy(self, state, slow_bw, app_bw, k):
         n = state.last_obs.shape[0]
-        _, top = jax.lax.top_k(state.last_obs, k)     # desc, ties by index
+        _, top = top_k(state.last_obs, k)     # desc, ties by index
         target = jnp.zeros((n,), bool).at[top].set(True)
         idx = jnp.arange(n, dtype=jnp.int32)
         promote, n_p = ranked_take(idx, target & ~state.in_fast,

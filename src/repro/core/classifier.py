@@ -21,10 +21,10 @@ form the hot set — no hotness threshold, no cooling (EWMA decay subsumes it).
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.state import (MODE_RECENCY, ARMSConfig, TieringState)
+from repro.utils.topk import top_k
 
 
 def score_weights(cfg: ARMSConfig, mode):
@@ -62,11 +62,11 @@ def update_scores(state: TieringState, access_counts, cfg: ARMSConfig,
 def topk_hot_mask(score: jnp.ndarray, k: int):
     """Boolean mask of the top-k pages by score (Algorithm 1 lines 7-9).
 
-    Ties are broken by page index (stable) via jax.lax.top_k semantics.
+    Ties are broken by page index (``utils.topk.top_k``).
     """
     n = score.shape[0]
     k = min(int(k), n)
-    _, idx = jax.lax.top_k(score, k)
+    _, idx = top_k(score, k)
     mask = jnp.zeros((n,), bool).at[idx].set(True)
     return mask, idx
 

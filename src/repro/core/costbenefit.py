@@ -15,10 +15,10 @@ the migration engine), making the gate self-calibrating — no threshold.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.state import ARMSConfig, TieringState
+from repro.utils.topk import top_k
 
 _NEG = jnp.float32(-3.4e38)
 _POS = jnp.float32(3.4e38)
@@ -35,7 +35,7 @@ def promotion_candidates(state: TieringState, hot_mask, cfg: ARMSConfig,
                & (state.score >= state.prev_score)
                & (state.hot_age >= cfg.hot_age_min))
     keyed = jnp.where(is_cand, state.score, _NEG)
-    val, idx = jax.lax.top_k(keyed, bs_max)
+    val, idx = top_k(keyed, bs_max)
     return idx, val > _NEG
 
 
@@ -43,7 +43,7 @@ def demotion_victims(state: TieringState, hot_mask, bs_max: int):
     """Coldest fast-tier pages outside the top-k, coldest first."""
     is_victim = state.in_fast & (~hot_mask)
     keyed = jnp.where(is_victim, -state.score, _NEG)
-    val, idx = jax.lax.top_k(keyed, bs_max)
+    val, idx = top_k(keyed, bs_max)
     return idx, val > _NEG
 
 

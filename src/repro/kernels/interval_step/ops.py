@@ -3,9 +3,14 @@
 Routing (resolved once per process via ``kernels/_backend``):
 
   * TPU backend           -> compiled Pallas kernels (kernel.py);
-  * ``REPRO_FORCE_INTERPRET`` -> interpret-mode Pallas kernels — the
-    validation route the kernel-vs-ref CI tests pin on CPU containers;
+  * ``REPRO_FORCE_INTERPRET`` off-TPU -> interpret-mode Pallas kernels —
+    the validation route the kernel-vs-ref checks pin on CPU hosts (on a
+    TPU the variable is an error, see ``kernels/_backend``);
   * any other backend     -> the fused jnp references (ref.py).
+
+``tier_migrate`` keeps a lane's page row and plans in SMEM; shapes past
+that budget (``kernel.tier_migrate_fits``) take the jnp reference on
+every backend.
 
 The references are the kernels' bitwise contract, so the scan engine's
 CRN equivalence guarantees hold on every route.  Unlike the other
@@ -39,7 +44,8 @@ def tier_migrate(tier, promote, demote, caps):
     unique page indices (the padded-index contract) — the sequential
     kernel and the vectorized reference only coincide under it.
     """
-    if _pallas():
+    if _pallas() and kernel.tier_migrate_fits(
+            tier.shape[1], promote.shape[1], demote.shape[1]):
         return kernel.tier_migrate_kernel(tier, promote, demote, caps,
                                           interpret=interpret_mode())
     return ref.tier_migrate_ref(tier, promote, demote, caps)

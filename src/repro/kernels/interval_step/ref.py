@@ -73,11 +73,13 @@ def interval_account_ref(mach, true, tier, mig_up, mig_down, oracle, k: int):
     ``true`` f32 [B, n]; ``tier`` i32 [B, n]; ``mig_up``/``mig_down`` f32
     [B, R-1]; ``oracle`` bool [B, n].  Returns (acc_fast, acc_slow, wall,
     slow_share, app_raw, recall), each [B] f32 — the first five bitwise
-    those of ``vmap(simjax.interval_accounting_impl)``, recall the scan
-    engine's ``((tier == 0) & oracle).sum / k``.
+    those of ``simjax.interval_accounting_impl`` mapped lane by lane (the
+    scan engine's ``_per_lane``), recall the scan engine's
+    ``((tier == 0) & oracle).sum / k``.
     """
-    acc_fast, acc_slow, wall, slow_share, app_raw = jax.vmap(
-        simjax.interval_accounting_impl)(mach, true, tier, mig_up, mig_down)
+    acc_fast, acc_slow, wall, slow_share, app_raw = jax.lax.map(
+        lambda a: simjax.interval_accounting_impl(*a),
+        (mach, true, tier, mig_up, mig_down))
     recall = ((tier == 0) & oracle).sum(axis=1).astype(jnp.float32) / k
     return acc_fast, acc_slow, wall, slow_share, app_raw, recall
 

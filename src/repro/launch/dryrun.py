@@ -39,9 +39,6 @@ GRAD_ACCUM = 8
 
 
 def _sanitize(d):
-    # cost_analysis() returns a single dict on newer jax, [dict] on older.
-    if isinstance(d, (list, tuple)):
-        d = d[0] if d else {}
     out = {}
     for k, v in (d or {}).items():
         try:

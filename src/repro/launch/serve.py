@@ -34,6 +34,7 @@ from repro.configs import registry
 from repro.models import model as M
 from repro.tiering import paged_kv as PK
 from repro.tiering import tiered_pool as TP
+from repro.utils.compilation import count_compiles, setup_compile_cache
 
 
 @dataclasses.dataclass
@@ -51,6 +52,14 @@ class ServeReport:
     telemetry: dict          # full tiered_pool.telemetry record
     trace: object = None     # TraceWorkload when capture=True
     kv: object = None        # final PagedKV (tests inspect the pools)
+    tokens: np.ndarray = None       # [batch, n_tokens] generated ids
+    last_logits: np.ndarray = None  # [batch, vocab] f32 of the last step
+    decode_compiles: int = 0  # executables built after the first token
+
+
+#: the model's decode step, compiled once per (config, shapes): the layer
+#: scan inside it is traced again on every call of the plain function.
+_decode_step = jax.jit(M.decode_step, static_argnums=(4,))
 
 
 def serve(arch: str, n_tokens: int, batch: int, full: bool = False,
@@ -88,29 +97,37 @@ def serve(arch: str, n_tokens: int, batch: int, full: bool = False,
     # long-EWMA attention mass (the legacy fast-mass telemetry): the
     # share of DECAYED mass resident fast, not just this step's slice.
     mass_ewma = jnp.zeros((n_pages,), jnp.float32)
-    for t in range(n_tokens):
-        logits, cache = M.decode_step(params, token, cache, jnp.int32(t),
-                                      cfg)
-        token = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-        # drive the tiered layer with this step's q/k/v telemetry; K and V
-        # are DISTINCT streams (the pools must be allowed to diverge).
-        q = jax.random.normal(jax.random.fold_in(rng, 3 * t),
-                              (batch, cfg.n_heads, cfg.head_dim))
-        k_new = jax.random.normal(jax.random.fold_in(rng, 3 * t + 1),
-                                  (batch, cfg.n_kv_heads, cfg.head_dim))
-        v_new = jax.random.normal(jax.random.fold_in(rng, 3 * t + 2),
-                                  (batch, cfg.n_kv_heads, cfg.head_dim))
-        _, kv, plan = PK.serve_decode_step(kv, q, k_new, v_new,
-                                           jnp.int32(t), pk_cfg)
-        mass_ewma = 0.98 * mass_ewma + plan.access
-        shares.append((mass_ewma * kv.pool.in_fast).sum()
-                      / jnp.maximum(mass_ewma.sum(), 1e-9))
-        if capture:
-            masses.append(plan.access)
-        if sync_telemetry:
-            # legacy per-token host-sync path (perf comparison only)
-            promotions_sync += int(plan.count)
-            float(plan.fast_share)
+    tokens = []    # device [batch, 1] ids; one transfer after the loop
+    after_first = 0
+    with count_compiles() as compiles:
+        for t in range(n_tokens):
+            if t == 1:
+                after_first = compiles.count
+            logits, cache = _decode_step(params, token, cache,
+                                         jnp.int32(t), cfg)
+            token = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+            tokens.append(token)
+            # drive the tiered layer with this step's q/k/v telemetry; K
+            # and V are DISTINCT streams (the pools must be allowed to
+            # diverge).
+            q = jax.random.normal(jax.random.fold_in(rng, 3 * t),
+                                  (batch, cfg.n_heads, cfg.head_dim))
+            k_new = jax.random.normal(jax.random.fold_in(rng, 3 * t + 1),
+                                      (batch, cfg.n_kv_heads, cfg.head_dim))
+            v_new = jax.random.normal(jax.random.fold_in(rng, 3 * t + 2),
+                                      (batch, cfg.n_kv_heads, cfg.head_dim))
+            _, kv, plan = PK.serve_decode_step(kv, q, k_new, v_new,
+                                               jnp.int32(t), pk_cfg)
+            mass_ewma = 0.98 * mass_ewma + plan.access
+            shares.append((mass_ewma * kv.pool.in_fast).sum()
+                          / jnp.maximum(mass_ewma.sum(), 1e-9))
+            if capture:
+                masses.append(plan.access)
+            if sync_telemetry:
+                # legacy per-token host-sync path (perf comparison only)
+                promotions_sync += int(plan.count)
+                float(plan.fast_share)
+    decode_compiles = compiles.count - after_first if n_tokens > 1 else 0
     jax.block_until_ready(kv.pool)
     dt = time.time() - t0
     tok_s = n_tokens * batch / dt
@@ -130,7 +147,10 @@ def serve(arch: str, n_tokens: int, batch: int, full: bool = False,
         promotions=tele["promotions"], demotions=tele["demotions"],
         wasteful=tele["wasteful"], thrash=tele["thrash"],
         slowdown=tele["slowdown"], fast_mass=fast_mass,
-        telemetry=tele, trace=trace, kv=kv)
+        telemetry=tele, trace=trace, kv=kv,
+        tokens=np.asarray(jnp.concatenate(tokens, axis=1)),
+        last_logits=np.asarray(logits[:, -1], np.float32),
+        decode_compiles=decode_compiles)
     if not quiet:
         print(f"[serve] {arch}/{rep.policy}: {n_tokens} steps x {batch} "
               f"seqs = {tok_s:,.0f} tok/s"
@@ -160,6 +180,7 @@ def main():
                     help="save the paged-KV access trace as an .npz "
                          "TraceWorkload")
     args = ap.parse_args()
+    setup_compile_cache()
     rep = serve(args.arch, args.tokens, args.batch, full=args.full,
                 policy=args.policy, machine=args.machine, seed=args.seed,
                 sync_telemetry=args.sync_telemetry,

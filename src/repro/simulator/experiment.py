@@ -203,7 +203,9 @@ def sweep(policies, *, workloads=None, trace=None, machines="pmem-large",
     (grouped = historical one-dispatch-per-family, the union path's
     bitwise reference).  ``mesh`` shards the lane axis over devices:
     ``None`` (no sharding), ``"auto"`` (all local devices), or an int
-    device count — results are bitwise-identical at any mesh size;
+    device count — results are bitwise-identical at any mesh size
+    (on a TPU backend, sizes above 1 are refused until shown on several
+    chips: ``fabric.resolve_mesh``);
     padded lanes are dropped before labeling.  ``_pad_multiple`` is
     test-only: it forces lane padding even on a 1-device mesh so the
     padding/labeling honesty is regression-testable anywhere.

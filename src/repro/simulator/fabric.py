@@ -26,7 +26,14 @@ removes:
       shard skipping an interval another shard fires on changes nothing;
     - synth lanes gather their workload row by GLOBAL workload index
       (``widx``) from the replicated [W] synthesis — value-wise exactly
-      the unsharded ``repeat``.
+      the unsharded ``repeat``;
+    - no lane's numerics depend on how many lanes share its program.
+      On a TPU v5e both a batched f32 row sum and ``lax.top_k``'s tie
+      order changed with the lane count (PERF.md), so the interval
+      accounting sums one lane per loop step (``scan_engine._per_lane``)
+      and rankings use ``utils.topk.top_k``.  Until a run on several
+      chips confirms it, ``resolve_mesh`` refuses mesh sizes above 1 on
+      a TPU backend.
 
   Streaming aggregation (``reduce="stream"``) already makes outputs
   O(lanes); the fabric's only cross-device traffic is the final
@@ -59,7 +66,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.baselines.protocol import SENTINEL, PolicySpec
@@ -319,16 +325,27 @@ def resolve_mesh(mesh) -> int | None:
     ``None`` never shards; ``"auto"`` shards over every local device
     (plain path on a single-device host); an int forces that many
     devices (1 is allowed — the forced-shard_map equivalence tests).
+
+    On a TPU backend D > 1 raises: the lane-count fix is shown bitwise on
+    one chip (the shards' programs run one after another), but no run on
+    several chips has yet compared mesh D with mesh 1 (PERF.md, open
+    questions).  Lift this once ``chip_smoke.py --chips 4`` passes.
     """
     if mesh is None:
         return None
     if mesh == "auto":
         d = jax.device_count()
-        return d if d > 1 else None
-    d = int(mesh)
-    if not 1 <= d <= jax.device_count():
-        raise ValueError(f"mesh={d} but only {jax.device_count()} "
-                         "device(s) are available")
+        d = d if d > 1 else None
+    else:
+        d = int(mesh)
+        if not 1 <= d <= jax.device_count():
+            raise ValueError(f"mesh={d} but only {jax.device_count()} "
+                             "device(s) are available")
+    if d is not None and d > 1 and jax.default_backend() == "tpu":
+        raise NotImplementedError(
+            f"mesh={mesh}: a lane-sharded sweep over {d} TPU chips is not "
+            "yet shown bitwise equal to mesh=1 on real chips (PERF.md, "
+            "open questions); use mesh=None or mesh=1")
     return d
 
 
@@ -374,14 +391,14 @@ def _fab_trace_jit(spec, trace, oracle_mask, k, mach, caps, keys, sample,
                    sampling, need_normal, interval_kernel, reduce,
                    tier_shim, mesh):
     lane, rep = P(LANE_AXIS), P()
-    f = shard_map(
+    f = jax.shard_map(
         lambda sp, tr, om, mc, cp, ky, sm: scan_engine._simulate(
             sp, tr, om, k, mc, cp, ky, sm, sampling, need_normal,
             interval_kernel=interval_kernel, reduce=reduce,
             tier_shim=tier_shim),
         mesh=mesh,
         in_specs=(lane, rep, rep, lane, lane, lane, rep),
-        out_specs=_out_specs(reduce), check_rep=False)
+        out_specs=_out_specs(reduce), check_vma=False)
     return f(spec, trace, oracle_mask, mach, caps, keys, sample)
 
 
@@ -397,7 +414,7 @@ def _fab_synth_jit(spec, wl, k, mach, caps, keys, sample, noise_key,
     # dispatches (CRN pairing) and never donated; widx (9) is rebuilt per
     # call and is.
     lane, rep = P(LANE_AXIS), P()
-    f = shard_map(
+    f = jax.shard_map(
         lambda sp, w, mc, cp, ky, sm, nk, wk, wi: scan_engine._simulate(
             sp, None, None, k, mc, cp, ky, sm, sampling, need_normal,
             wl=w, wl_keys=wk, noise_key=nk, n=n, wl_boost=wl_boost,
@@ -405,7 +422,7 @@ def _fab_synth_jit(spec, wl, k, mach, caps, keys, sample, noise_key,
             tier_shim=tier_shim, widx=wi),
         mesh=mesh,
         in_specs=(lane, rep, lane, lane, lane, rep, rep, rep, lane),
-        out_specs=_out_specs(reduce), check_rep=False)
+        out_specs=_out_specs(reduce), check_vma=False)
     return f(spec, wl, mach, caps, keys, sample, noise_key, wl_keys, widx)
 
 

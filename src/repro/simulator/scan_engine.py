@@ -77,6 +77,7 @@ from repro.simulator import machine_spec, machines, simjax, workload_spec
 from repro.simulator.engine import SimResult, oracle_topk_masks
 from repro.simulator.sampling import (_NORMAL_SWITCH, pebs_sample_from_uniform,
                                       synth_uniform_row, uniform_field)
+from repro.utils.topk import top_k
 
 __all__ = [
     "SWEEPABLE", "simulate", "sweep_seeds", "sweep_policy_configs",
@@ -152,6 +153,22 @@ def _bwhere(pred, a, b):
                                x, y), a, b)
 
 
+def _per_lane(fn, *args):
+    """``fn`` over the leading lane axis of ``args``, one lane per loop
+    step (``lax.map``) instead of batched (``vmap``).
+
+    For the interval accounting's f32 page-row sums, which feed both the
+    statistics and tier-native policy budgets: batched over [B, n] on a
+    TPU v5e, a lane's row sum came out differently at different lane
+    counts B, so a lane's results depended on how many lanes shared its
+    program — its mesh shard (fabric.py).  Each loop step reduces
+    one [n] row, the same computation at any B, and the same one the
+    numpy engine's jitted single-lane ``simjax.interval_accounting``
+    runs.
+    """
+    return jax.lax.map(lambda a: fn(*a), args)
+
+
 def _lane_specs(spec, B: int):
     """Broadcast one spec's leaves to B identical sweep lanes."""
     return jax.tree_util.tree_map(
@@ -188,7 +205,7 @@ def _topk_mask(x, k: int):
     """Device oracle mask: exact top-k of ``x``, tie rule identical to the
     host ``oracle_topk_masks`` (strictly-greater first, then ascending
     index among threshold-equal values — ``lax.top_k``'s rule)."""
-    _, idx = jax.lax.top_k(x, k)
+    _, idx = top_k(x, k)
     return jnp.zeros(x.shape, bool).at[idx].set(True)
 
 
@@ -425,8 +442,8 @@ def _simulate(spec, trace, oracle_mask, k: int, mach, caps, keys, sample,
                         mach, true_b, tier, mig_up.astype(f32),
                         mig_down.astype(f32), orc_b, k)
             else:
-                acc_fast, acc_slow, wall, slow_share, app_raw = jax.vmap(
-                    simjax.interval_accounting_impl)(
+                acc_fast, acc_slow, wall, slow_share, app_raw = _per_lane(
+                    simjax.interval_accounting_impl,
                     mach, true_b, tier, mig_up.astype(f32),
                     mig_down.astype(f32))
                 recall = ((tier == 0) & orc_b).sum(axis=1).astype(f32) / k
@@ -488,8 +505,8 @@ def _simulate(spec, trace, oracle_mask, k: int, mach, caps, keys, sample,
                 simjax.wasteful_update, in_axes=(None, 0, 0, 0, 0, 0, 0))(
                 t - 1, c["promoted_at"], c["demoted_at"], promote, demote,
                 pexec, dexec)
-            acc_fast, acc_slow, wall, slow_share, app_raw = jax.vmap(
-                simjax.interval_accounting_impl)(
+            acc_fast, acc_slow, wall, slow_share, app_raw = _per_lane(
+                simjax.interval_accounting_impl,
                 mach, true_b, tier, mig_up.astype(f32),
                 mig_down.astype(f32))
             recall = ((tier == 0) & orc_b).sum(axis=1).astype(f32) / k
@@ -522,7 +539,8 @@ def _simulate(spec, trace, oracle_mask, k: int, mach, caps, keys, sample,
             acc_total=c["acc_total"] + acc_fast + acc_slow,
             recall_sum=c["recall_sum"] + recall)
         if tn:
-            new_c["tier_util"] = jax.vmap(simjax.tier_utilization_impl)(
+            new_c["tier_util"] = _per_lane(
+                simjax.tier_utilization_impl,
                 mach, true_b, tier, mig_up.astype(f32),
                 mig_down.astype(f32))
         if wl is not None:

@@ -271,3 +271,27 @@ def test_recency_mode_doubles_sampling_and_policy_rate():
         int(sampling_period(jnp.int32(MODE_HISTORY)))
     assert int(policy_every(jnp.int32(MODE_RECENCY))) < \
         int(policy_every(jnp.int32(MODE_HISTORY)))
+
+
+# ------------------------------------------------------------------ top-k
+@pytest.mark.parametrize("rows,n,k", [(1, 64, 5), (5, 1000, 37),
+                                      (42, 512, 256), (8, 129, 129)])
+def test_top_k_sorted_matches_lax_top_k(rows, n, k):
+    """The TPU route of ``utils.topk.top_k`` (a two-key sort) keeps
+    ``lax.top_k``'s values, order and tie rule on tie-heavy rows with
+    signed zeros and -inf, and a row's answer does not depend on how many
+    rows share the call."""
+    from repro.utils.topk import top_k_sorted
+
+    rng = np.random.default_rng(rows * n + k)
+    x = np.floor(rng.random((rows, n)) * 6).astype(np.float32) - 2.0
+    x[rng.random((rows, n)) < 0.1] = -np.inf
+    x[rng.random((rows, n)) < 0.1] = -0.0
+    x = jnp.asarray(x)
+    want_v, want_i = jax.lax.top_k(x, k)
+    got_v, got_i = top_k_sorted(x, k)
+    np.testing.assert_array_equal(got_i, want_i)
+    np.testing.assert_array_equal(np.signbit(got_v), np.signbit(want_v))
+    np.testing.assert_array_equal(got_v, want_v)
+    one_v, one_i = top_k_sorted(x[-1:], k)
+    np.testing.assert_array_equal(one_i[0], got_i[-1])

@@ -184,6 +184,19 @@ class TestShardingSingleDevice:
         with pytest.raises(ValueError, match="device"):
             fabric.resolve_mesh(jax.device_count() + 1)
 
+    @pytest.mark.parametrize("mesh", [4, "auto"])
+    def test_multi_chip_mesh_refused_on_tpu(self, monkeypatch, mesh):
+        """Sharding over several TPU chips is refused, loudly, until a
+        four-chip run shows it bitwise; one device and the plain path
+        stay available."""
+        import jax
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(jax, "device_count", lambda: 4)
+        with pytest.raises(NotImplementedError, match="PERF.md"):
+            fabric.resolve_mesh(mesh)
+        assert fabric.resolve_mesh(1) == 1
+        assert fabric.resolve_mesh(None) is None
+
 
 # --------------------------------------------------- dispatch accounting
 class TestCountDispatches:

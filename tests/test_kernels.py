@@ -154,3 +154,69 @@ class TestMambaScan:
                                    **TOL[dtype])
         np.testing.assert_allclose(np.asarray(h), np.asarray(h_ref),
                                    rtol=1e-4, atol=1e-4)
+
+
+class TestBackendRouting:
+    """On a TPU the kernels always run compiled: the interpret switch is
+    an error there, and the chip smoke check refuses any other platform."""
+
+    @pytest.fixture
+    def fresh_probe(self, monkeypatch):
+        from repro.kernels import _backend
+        monkeypatch.setattr(_backend, "_INTERPRET", None)
+        return _backend
+
+    def test_force_interpret_on_tpu_raises(self, fresh_probe, monkeypatch):
+        monkeypatch.setenv("REPRO_FORCE_INTERPRET", "1")
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(RuntimeError, match="REPRO_FORCE_INTERPRET"):
+            fresh_probe.interpret_mode()
+
+    def test_force_interpret_off_tpu_interprets(self, fresh_probe,
+                                                monkeypatch):
+        monkeypatch.setenv("REPRO_FORCE_INTERPRET", "1")
+        assert jax.default_backend() == "cpu"
+        assert fresh_probe.interpret_mode()
+
+    def test_chip_smoke_refuses_cpu(self):
+        import importlib.util
+        import os
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        spec = importlib.util.spec_from_file_location(
+            "chip_smoke", os.path.join(root, "chip_smoke.py"))
+        chip_smoke = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(chip_smoke)
+        with pytest.raises(SystemExit) as exc:
+            chip_smoke.main([])
+        assert exc.value.code not in (0, None)
+        assert "'cpu'" in str(exc.value.code)
+
+
+class TestCompileCache:
+    """The entry points' cache helper keeps a directory the environment
+    names and otherwise picks one fixed path inside the checkout."""
+
+    @pytest.fixture
+    def restore_cache_dir(self):
+        was = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", was)
+
+    def test_env_dir_is_kept(self, monkeypatch, tmp_path,
+                             restore_cache_dir):
+        from repro.utils import compilation
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        before = jax.config.jax_compilation_cache_dir
+        assert compilation.setup_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_default_is_fixed_repo_path(self, monkeypatch,
+                                        restore_cache_dir):
+        import os
+        from repro.utils import compilation
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(root, ".jax_cache")
+        assert compilation.setup_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert compilation.setup_compile_cache() == want     # stable

@@ -246,3 +246,24 @@ class TestServePolicies:
         assert rep.policy == policy
         assert rep.fast_mass.shape == (12,)
         assert np.isfinite(rep.slowdown) and rep.slowdown > 0.0
+
+
+class TestServeCompilesOnce:
+    """The decode step is jitted once per (config, shapes): a warm
+    ``serve()`` builds the same executables whatever its token count (the
+    unjitted step re-traced its layer scan, one compile per token)."""
+
+    def test_warm_serve_compiles_independent_of_tokens(self):
+        from repro.launch.serve import serve
+        from repro.utils.compilation import count_compiles
+        serve("stablelm-1.6b", n_tokens=4, batch=2, quiet=True)   # warm
+        built = {}
+        for n in (8, 16):
+            with count_compiles() as ctr:
+                rep = serve("stablelm-1.6b", n_tokens=n, batch=2,
+                            quiet=True)
+            built[n] = ctr.count
+            assert rep.decode_compiles == 0
+            assert rep.tokens.shape == (2, n)
+            assert np.isfinite(rep.last_logits).all()
+        assert built[8] == built[16]
