@@ -35,10 +35,12 @@ are paired.  With multiple seeds the sampling switches to per-lane
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.baselines.arms_policy import ARMSSpec
 from repro.baselines.hemem import HeMemSpec
@@ -170,6 +172,7 @@ def _resolve_workloads(workloads, T):
     return specs, names
 
 
+@functools.partial(jax.profiler.annotate_function, name="experiment.sweep")
 def sweep(policies, *, workloads=None, trace=None, machines="pmem-large",
           seeds=(0,), k: int, T: int | None = None, n: int | None = None,
           sim_seed: int = 0, wl_seed: int = 0, sample_u=None,
@@ -209,82 +212,90 @@ def sweep(policies, *, workloads=None, trace=None, machines="pmem-large",
     padded lanes are dropped before labeling.  ``_pad_multiple`` is
     test-only: it forces lane padding even on a 1-device mesh so the
     padding/labeling honesty is regression-testable anywhere.
+
+    While a profiler trace records, the call marks its phases as host
+    spans on the trace's clock: ``experiment.sweep`` around it all,
+    ``experiment.build`` (once for the shared specs, then per group for
+    its lane layout and keys), and per group ``experiment.dispatch``
+    (the enqueue), ``experiment.wait`` (until the results exist) and
+    ``experiment.readback`` (``_to_result`` over the group's lanes).
     """
     reduce = "stack" if timelines else "stream"
-    policies = [policies] if not isinstance(policies, (list, tuple)) \
-        else list(policies)
-    pol_specs = [policy_spec(p) for p in policies]
-    machines_in = [machines] if not isinstance(machines, (list, tuple)) \
-        else list(machines)
-    mach_specs = [machines_mod.get(m) for m in machines_in]
-    mach_labels = _machine_labels(machines_in, mach_specs)
-    seeds = list(seeds)
-    P, M, S = len(pol_specs), len(mach_specs), len(seeds)
-    if not (P and M and S):
-        raise ValueError("every axis needs at least one entry")
-
-    synth = workloads is not None
-    if synth:
-        if trace is not None:
-            raise ValueError("pass either trace or workloads, not both")
-        if T is None or n is None:
-            raise ValueError("workload-synthesis mode needs T and n")
-        if not list(workloads):
+    with TraceAnnotation("experiment.build"):
+        policies = [policies] if not isinstance(policies, (list, tuple)) \
+            else list(policies)
+        pol_specs = [policy_spec(p) for p in policies]
+        machines_in = [machines] if not isinstance(machines, (list, tuple)) \
+            else list(machines)
+        mach_specs = [machines_mod.get(m) for m in machines_in]
+        mach_labels = _machine_labels(machines_in, mach_specs)
+        seeds = list(seeds)
+        P, M, S = len(pol_specs), len(mach_specs), len(seeds)
+        if not (P and M and S):
             raise ValueError("every axis needs at least one entry")
-        wl_specs, wl_names = _resolve_workloads(list(workloads), T)
-        W = len(wl_specs)
-        wl = scan_engine._stack_workloads(wl_specs)
-        wl_boost = any(w.has_boost() for w in wl_specs)
-    else:
-        if trace is None:
-            raise ValueError("need a trace or a workloads list")
-        trace = np.asarray(trace)
-        T, n = trace.shape
-        W, wl_names = 1, ["trace"]
-        oracle = oracle_topk_masks(trace, k)
-    assert 0 < k <= n
 
-    if sample_u is not None:
-        if S > 1:
-            # "crn" never consumes the per-lane keys: the seed lanes would
-            # be silent bitwise copies of each other.
-            raise ValueError("sample_u fixes the noise for every lane; "
-                             "it cannot be combined with a seeds axis")
-        sampling = "crn"
-        sample = jnp.asarray(sample_u, jnp.float32)
-        assert sample.shape == (T, n)
-    elif S == 1:
-        # paired comparisons: every lane shares one CRN noise source.
-        sampling = "crn" if not synth else "crn_prng"
-        sample = (jnp.asarray(uniform_field(T, n, seed=sim_seed))
-                  if not synth else jnp.zeros((T, 1), jnp.float32))
-    else:
-        sampling = "prng"
-        sample = jnp.zeros((T, 1), jnp.float32)
+        synth = workloads is not None
+        if synth:
+            if trace is not None:
+                raise ValueError("pass either trace or workloads, not both")
+            if T is None or n is None:
+                raise ValueError("workload-synthesis mode needs T and n")
+            if not list(workloads):
+                raise ValueError("every axis needs at least one entry")
+            wl_specs, wl_names = _resolve_workloads(list(workloads), T)
+            W = len(wl_specs)
+            wl = scan_engine._stack_workloads(wl_specs)
+            wl_boost = any(w.has_boost() for w in wl_specs)
+        else:
+            if trace is None:
+                raise ValueError("need a trace or a workloads list")
+            trace = np.asarray(trace)
+            T, n = trace.shape
+            W, wl_names = 1, ["trace"]
+            oracle = oracle_topk_masks(trace, k)
+        assert 0 < k <= n
 
-    # group same-family policies: different state pytrees cannot stack —
-    # unless the union fabric fuses the mixed panel into ONE group (and
-    # therefore ONE compiled program).
-    if dispatch not in ("auto", "union", "grouped"):
-        raise ValueError(f"dispatch={dispatch!r}; "
-                         "expected auto | union | grouped")
-    mach_all, caps_all = machine_spec.lane_stack(mach_specs, n, k)
-    n_families = len({jax.tree_util.tree_structure(sp)
-                      for sp in pol_specs})
-    use_union = dispatch == "union" or (dispatch == "auto"
-                                        and n_families > 1)
-    if use_union:
-        lane_specs = fabric.build_union(pol_specs, n, k, mach_all)
-        groups = {fabric.UnionSpec: list(range(P))}
-    else:
-        lane_specs = pol_specs
-        # key on the TREEDEF (class + meta), not the class: same-family
-        # specs with different meta (e.g. migration_limit) have different
-        # pad widths and cannot stack leaf-wise.
-        groups = {}
-        for i, sp in enumerate(pol_specs):
-            groups.setdefault(jax.tree_util.tree_structure(sp),
-                              []).append(i)
+        if sample_u is not None:
+            if S > 1:
+                # "crn" never consumes the per-lane keys: the seed lanes would
+                # be silent bitwise copies of each other.
+                raise ValueError("sample_u fixes the noise for every lane; "
+                                 "it cannot be combined with a seeds axis")
+            sampling = "crn"
+            sample = jnp.asarray(sample_u, jnp.float32)
+            assert sample.shape == (T, n)
+        elif S == 1:
+            # paired comparisons: every lane shares one CRN noise source.
+            sampling = "crn" if not synth else "crn_prng"
+            sample = (jnp.asarray(uniform_field(T, n, seed=sim_seed))
+                      if not synth else jnp.zeros((T, 1), jnp.float32))
+        else:
+            sampling = "prng"
+            sample = jnp.zeros((T, 1), jnp.float32)
+
+        # group same-family policies: different state pytrees cannot stack —
+        # unless the union fabric fuses the mixed panel into ONE group (and
+        # therefore ONE compiled program).
+        if dispatch not in ("auto", "union", "grouped"):
+            raise ValueError(f"dispatch={dispatch!r}; "
+                             "expected auto | union | grouped")
+        mach_all, caps_all = machine_spec.lane_stack(mach_specs, n, k)
+        n_families = len({jax.tree_util.tree_structure(sp)
+                          for sp in pol_specs})
+        use_union = dispatch == "union" or (dispatch == "auto"
+                                            and n_families > 1)
+        if use_union:
+            lane_specs = fabric.build_union(pol_specs, n, k, mach_all)
+            groups = {fabric.UnionSpec: list(range(P))}
+        else:
+            lane_specs = pol_specs
+            # key on the TREEDEF (class + meta), not the class: same-family
+            # specs with different meta (e.g. migration_limit) have different
+            # pad widths and cannot stack leaf-wise.
+            groups = {}
+            for i, sp in enumerate(pol_specs):
+                groups.setdefault(jax.tree_util.tree_structure(sp),
+                                  []).append(i)
 
     grid = [None] * (P * W * M * S)
     for cls, idxs in groups.items():
@@ -294,47 +305,53 @@ def sweep(policies, *, workloads=None, trace=None, machines="pmem-large",
         p_local = (lane // (M * S)) % Pg
         m_of = (lane // S) % M
         s_of = lane % S
-        spec_l = scan_engine._take_lanes(
-            scan_engine._stack_specs([lane_specs[i] for i in idxs]),
-            jnp.asarray(p_local, jnp.int32))
-        mach_l = scan_engine._take_lanes(mach_all,
-                                         jnp.asarray(m_of, jnp.int32))
-        caps_l = jnp.take(caps_all, jnp.asarray(m_of, jnp.int32), axis=0)
-        keys = jnp.stack([jax.random.PRNGKey(int(seeds[s])) for s in s_of])
+        with TraceAnnotation("experiment.build"):
+            spec_l = scan_engine._take_lanes(
+                scan_engine._stack_specs([lane_specs[i] for i in idxs]),
+                jnp.asarray(p_local, jnp.int32))
+            mach_l = scan_engine._take_lanes(mach_all,
+                                             jnp.asarray(m_of, jnp.int32))
+            caps_l = jnp.take(caps_all, jnp.asarray(m_of, jnp.int32), axis=0)
+            keys = jnp.stack([jax.random.PRNGKey(int(seeds[s]))
+                              for s in s_of])
         min_period = min(lane_specs[i].min_sampling_period() for i in idxs)
-        if synth:
-            out, finfo = fabric.sim_synth(
-                spec_l, wl, k, mach_l, caps_l, keys, sample,
-                jax.random.PRNGKey(sim_seed),
-                jnp.stack([jax.random.PRNGKey(wl_seed)] * W),
-                sampling,
-                scan_engine._synth_need_normal(wl_specs, min_period),
-                Pg * M * S, n, wl_boost=wl_boost,
-                interval_kernel=use_interval_kernel, reduce=reduce,
-                mesh=mesh, pad_multiple=_pad_multiple)
-        else:
-            out, finfo = fabric.sim_trace(
-                spec_l, jnp.asarray(trace, jnp.float32),
-                jnp.asarray(oracle), k, mach_l, caps_l, keys, sample,
-                sampling, scan_engine._need_normal(trace, min_period),
-                interval_kernel=use_interval_kernel, reduce=reduce,
-                mesh=mesh, pad_multiple=_pad_multiple)
-        out = scan_engine._timelines_lane_major(out)
+        with TraceAnnotation("experiment.dispatch"):
+            if synth:
+                out, finfo = fabric.sim_synth(
+                    spec_l, wl, k, mach_l, caps_l, keys, sample,
+                    jax.random.PRNGKey(sim_seed),
+                    jnp.stack([jax.random.PRNGKey(wl_seed)] * W),
+                    sampling,
+                    scan_engine._synth_need_normal(wl_specs, min_period),
+                    Pg * M * S, n, wl_boost=wl_boost,
+                    interval_kernel=use_interval_kernel, reduce=reduce,
+                    mesh=mesh, pad_multiple=_pad_multiple)
+            else:
+                out, finfo = fabric.sim_trace(
+                    spec_l, jnp.asarray(trace, jnp.float32),
+                    jnp.asarray(oracle), k, mach_l, caps_l, keys, sample,
+                    sampling, scan_engine._need_normal(trace, min_period),
+                    interval_kernel=use_interval_kernel, reduce=reduce,
+                    mesh=mesh, pad_multiple=_pad_multiple)
+            out = scan_engine._timelines_lane_major(out)
         scan_engine._record_dispatch(
             lanes=L, sampling=sampling, policy=lane_specs[idxs[0]].name,
             synth=synth, workloads=W, configs=Pg, machines=M, seeds=S, T=T,
             axis_product=True, interval_kernel=use_interval_kernel,
             reduce=reduce, dispatch="union" if use_union else "grouped",
             families=n_families if use_union else 1, **finfo)
-        for l in range(L):
-            w = l // (Pg * M * S)
-            p = idxs[p_local[l]]
-            m, s = m_of[l], s_of[l]
-            name = f"{pol_specs[p].name}@{wl_names[w]}[{mach_labels[m]}]"
-            if S > 1:
-                name += f"[seed={seeds[s]}]"
-            grid[((p * W + w) * M + m) * S + s] = scan_engine._to_result(
-                out, l, name)
+        with TraceAnnotation("experiment.wait"):
+            jax.block_until_ready(out)
+        with TraceAnnotation("experiment.readback"):
+            for l in range(L):
+                w = l // (Pg * M * S)
+                p = idxs[p_local[l]]
+                m, s = m_of[l], s_of[l]
+                name = f"{pol_specs[p].name}@{wl_names[w]}[{mach_labels[m]}]"
+                if S > 1:
+                    name += f"[seed={seeds[s]}]"
+                grid[((p * W + w) * M + m) * S + s] = scan_engine._to_result(
+                    out, l, name)
 
     axes = dict(policy=_dedup_labels([sp.name for sp in pol_specs]),
                 workload=_dedup_labels(wl_names),

@@ -150,7 +150,18 @@ class UnionSpec(PolicySpec):
         return tuple(out)
 
     def _switch(self, make_branch, *operands):
-        branches = [make_branch(f) for f in range(len(self.members))]
+        def scoped(f):
+            # the member's ops read ``<scope>/<member name>`` in the
+            # compiled program's op_name metadata (scan_engine scopes)
+            branch = make_branch(f)
+
+            def run(*ops):
+                with jax.named_scope(self.members[f].name):
+                    return branch(*ops)
+
+            return run
+
+        branches = [scoped(f) for f in range(len(self.members))]
         return jax.lax.switch(self.fam, branches, *operands)
 
     # --- shape contract --------------------------------------------------

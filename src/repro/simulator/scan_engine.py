@@ -83,18 +83,13 @@ __all__ = [
     "SWEEPABLE", "simulate", "sweep_seeds", "sweep_policy_configs",
     "arms_sim", "sweep_arms_configs", "simulate_workload",
     "sweep_workloads", "sweep_workload_configs", "last_dispatch",
-    "dispatch_count", "count_dispatches", "DispatchCounter",
+    "count_dispatches", "DispatchCounter",
 ]
 
 #: Info about the most recent compiled dispatch (lanes, sampling mode).
 #: The CI quick gates read this to assert tuning and machine sweeps stay
 #: lane-batched instead of silently regressing to a sequential loop.
 last_dispatch: dict = {}
-#: monotone count of compiled simulation dispatches this process has issued
-#: (every ``_record_dispatch`` call).  Kept for observability; callers that
-#: ASSERT on dispatch deltas use ``count_dispatches`` below — differencing
-#: the global races when two measured regions interleave.
-dispatch_count: int = 0
 
 
 class DispatchCounter:
@@ -123,10 +118,8 @@ def count_dispatches():
             experiment.sweep(...)
         assert ctr.count == 1 and ctr.last["lanes"] == L
 
-    Unlike read-and-reset differencing of the module-global
-    ``dispatch_count``, concurrent/nested measured regions cannot race:
-    each region owns its counter and only dispatches issued within the
-    region are tallied.
+    Concurrent/nested measured regions cannot race: each region owns its
+    counter and only dispatches issued within the region are tallied.
     """
     ctr = DispatchCounter()
     _active_counters.append(ctr)
@@ -345,50 +338,60 @@ def _simulate(spec, trace, oracle_mask, k: int, mach, caps, keys, sample,
             sampled = jnp.where(wt[:, None], true_b, sampled)
         return sampled
 
+    # The interval body is partitioned into five named scopes — synth,
+    # sample, policy, migrate, account — which reach the compiled
+    # program's op_name metadata and so the device trace (PERF.md,
+    # "Layers").  Scopes are metadata only: the computation is unchanged.
     def step(c, xs):
-        if wl is None:
-            true, orc, xs_sample = xs
-            true_b = jnp.broadcast_to(true[None], (B, n))        # [B, n]
-            orc_b = jnp.broadcast_to(orc[None], (B, n))
-            wst = None
-        else:
-            xs_sample = xs
-            wst, tw = c["wl_state"], c["t"]
-            due = jax.vmap(wl_cls.event_due, in_axes=(0, 0, None))(
-                wl, wst, tw)
-            # scalar any-lane gate: permutation redraws (sorts) only run
-            # on intervals where some workload lane has an event due.
-            wst = jax.lax.cond(
-                jnp.any(due),
-                lambda s: jax.vmap(
-                    lambda w, st_: wl_cls.event(w, st_, tw, wl_boost))(
-                    wl, s),
-                lambda s: s, wst)
-            probs = jax.vmap(wl_cls.probs_of, in_axes=(0, 0, None))(
-                wl, wst, tw)                                     # [W, n]
-            workt = jax.vmap(wl_cls.work_of, in_axes=(0, 0, None))(
-                wl, wst, tw)                                     # [W]
-            true_w = workt[:, None] * probs
-            orc_w = (interval_ops.topk_mask(true_w, k) if interval_kernel
-                     else jax.vmap(lambda x: _topk_mask(x, k))(true_w))
-            if widx is None:
-                true_b = jnp.repeat(true_w, wl_rep, axis=0)      # [B, n]
-                orc_b = jnp.repeat(orc_w, wl_rep, axis=0)
+        with jax.named_scope("synth"):
+            if wl is None:
+                true, orc, xs_sample = xs
+                true_b = jnp.broadcast_to(true[None], (B, n))    # [B, n]
+                orc_b = jnp.broadcast_to(orc[None], (B, n))
+                wst = None
             else:
-                # sharded lanes (fabric.py): every shard synthesizes the
-                # full replicated [W] workload stack and gathers its own
-                # lanes' rows by GLOBAL workload index — a row gather is
-                # value-wise exactly the ``repeat`` above, so shard
-                # results are bitwise the unsharded path's.
-                true_b = jnp.take(true_w, widx, axis=0)          # [B, n]
-                orc_b = jnp.take(orc_w, widx, axis=0)
+                xs_sample = xs
+                wst, tw = c["wl_state"], c["t"]
+                due = jax.vmap(wl_cls.event_due, in_axes=(0, 0, None))(
+                    wl, wst, tw)
+                # scalar any-lane gate: permutation redraws (sorts) only
+                # run on intervals where some workload lane has an event
+                # due.
+                wst = jax.lax.cond(
+                    jnp.any(due),
+                    lambda s: jax.vmap(
+                        lambda w, st_: wl_cls.event(w, st_, tw, wl_boost))(
+                        wl, s),
+                    lambda s: s, wst)
+                probs = jax.vmap(wl_cls.probs_of, in_axes=(0, 0, None))(
+                    wl, wst, tw)                                 # [W, n]
+                workt = jax.vmap(wl_cls.work_of, in_axes=(0, 0, None))(
+                    wl, wst, tw)                                 # [W]
+                true_w = workt[:, None] * probs
+                orc_w = (interval_ops.topk_mask(true_w, k)
+                         if interval_kernel
+                         else jax.vmap(lambda x: _topk_mask(x, k))(true_w))
+                if widx is None:
+                    true_b = jnp.repeat(true_w, wl_rep, axis=0)  # [B, n]
+                    orc_b = jnp.repeat(orc_w, wl_rep, axis=0)
+                else:
+                    # sharded lanes (fabric.py): every shard synthesizes
+                    # the full replicated [W] workload stack and gathers
+                    # its own lanes' rows by GLOBAL workload index — a row
+                    # gather is value-wise exactly the ``repeat`` above,
+                    # so shard results are bitwise the unsharded path's.
+                    true_b = jnp.take(true_w, widx, axis=0)      # [B, n]
+                    orc_b = jnp.take(orc_w, widx, axis=0)
         state = c["state"]
-        split = jax.vmap(jax.random.split, out_axes=1)(c["key"])
-        key, subs = split[0], split[1]
-        observed = observed_for(xs_sample, true_b, state, subs, c["t"])
+        with jax.named_scope("sample"):
+            split = jax.vmap(jax.random.split, out_axes=1)(c["key"])
+            key, subs = split[0], split[1]
+            observed = observed_for(xs_sample, true_b, state, subs, c["t"])
         t = c["t"] + 1
-        state = vobserve(spec, state, observed)
-        do = vfires(spec, state)                                # [B]
+        with jax.named_scope("policy"):
+            state = vobserve(spec, state, observed)
+            do = vfires(spec, state)                            # [B]
+            any_do = jnp.any(do)
 
         R = caps.shape[-1]
 
@@ -402,6 +405,12 @@ def _simulate(spec, trace, oracle_mask, k: int, mach, caps, keys, sample,
             demote = jnp.where(do[:, None], demote, -1)
             return st, promote, demote
 
+        def skip_moves(op):
+            st, tier0, p_at0, d_at0 = op
+            z = jnp.zeros((B,), jnp.int32)
+            zp = jnp.zeros((B, R - 1), jnp.int32)
+            return st, tier0, p_at0, d_at0, z, z, z, zp, zp
+
         if tn:
             # Tier-targeted route: the policy sees the per-tier utilization
             # and emits (pages, dst) moves; migrations + wasteful
@@ -409,44 +418,43 @@ def _simulate(spec, trace, oracle_mask, k: int, mach, caps, keys, sample,
             # no-op on skip intervals — all-(-1) pages execute nothing).
             def fire(op):
                 st, tier0, p_at0, d_at0 = op
-                st2, pages, dst = vtier_policy(
-                    spec, st, c["tier_util"], c["slow_bw"], c["app_bw"], k,
-                    caps)
-                st = _bwhere(do, st2, st)
-                pages = jnp.where(do[:, None], pages, -1)
-                tier, up_exec, down_exec, mig_up, mig_down = jax.vmap(
-                    simjax.apply_targeted_migrations)(tier0, pages, dst,
-                                                      caps)
-                waste, p_at, d_at = jax.vmap(
-                    simjax.wasteful_update,
-                    in_axes=(None, 0, 0, 0, 0, 0, 0))(
-                    t - 1, p_at0, d_at0, pages, pages, up_exec, down_exec)
-                return (st, tier, p_at, d_at,
-                        up_exec.sum(axis=1).astype(jnp.int32),
-                        down_exec.sum(axis=1).astype(jnp.int32), waste,
-                        mig_up, mig_down)
-
-            def skip(op):
-                st, tier0, p_at0, d_at0 = op
-                z = jnp.zeros((B,), jnp.int32)
-                zp = jnp.zeros((B, R - 1), jnp.int32)
-                return st, tier0, p_at0, d_at0, z, z, z, zp, zp
+                with jax.named_scope("policy"):
+                    st2, pages, dst = vtier_policy(
+                        spec, st, c["tier_util"], c["slow_bw"],
+                        c["app_bw"], k, caps)
+                    st = _bwhere(do, st2, st)
+                    pages = jnp.where(do[:, None], pages, -1)
+                with jax.named_scope("migrate"):
+                    tier, up_exec, down_exec, mig_up, mig_down = jax.vmap(
+                        simjax.apply_targeted_migrations)(tier0, pages, dst,
+                                                          caps)
+                    waste, p_at, d_at = jax.vmap(
+                        simjax.wasteful_update,
+                        in_axes=(None, 0, 0, 0, 0, 0, 0))(
+                        t - 1, p_at0, d_at0, pages, pages, up_exec,
+                        down_exec)
+                    return (st, tier, p_at, d_at,
+                            up_exec.sum(axis=1).astype(jnp.int32),
+                            down_exec.sum(axis=1).astype(jnp.int32), waste,
+                            mig_up, mig_down)
 
             (state, tier, promoted_at, demoted_at, n_promo, n_demo, waste,
              mig_up, mig_down) = jax.lax.cond(
-                jnp.any(do), fire, skip,
+                any_do, fire, skip_moves,
                 (state, c["tier"], c["promoted_at"], c["demoted_at"]))
-            if interval_kernel:
-                acc_fast, acc_slow, wall, slow_share, app_raw, recall = \
-                    interval_ops.interval_account(
-                        mach, true_b, tier, mig_up.astype(f32),
-                        mig_down.astype(f32), orc_b, k)
-            else:
-                acc_fast, acc_slow, wall, slow_share, app_raw = _per_lane(
-                    simjax.interval_accounting_impl,
-                    mach, true_b, tier, mig_up.astype(f32),
-                    mig_down.astype(f32))
-                recall = ((tier == 0) & orc_b).sum(axis=1).astype(f32) / k
+            with jax.named_scope("account"):
+                if interval_kernel:
+                    acc_fast, acc_slow, wall, slow_share, app_raw, recall = \
+                        interval_ops.interval_account(
+                            mach, true_b, tier, mig_up.astype(f32),
+                            mig_down.astype(f32), orc_b, k)
+                else:
+                    acc_fast, acc_slow, wall, slow_share, app_raw = \
+                        _per_lane(simjax.interval_accounting_impl,
+                                  mach, true_b, tier, mig_up.astype(f32),
+                                  mig_down.astype(f32))
+                    recall = ((tier == 0) & orc_b).sum(axis=1).astype(
+                        f32) / k
         elif interval_kernel:
             # Fused route: migrations + wasteful accounting ride INSIDE the
             # any-lane fire cond.  On non-fire intervals the unfused path
@@ -455,35 +463,34 @@ def _simulate(spec, trace, oracle_mask, k: int, mach, caps, keys, sample,
             # dropping the hop-chain gather/scatter from most intervals.
             def fire(op):
                 st, tier0, p_at0, d_at0 = op
-                st, promote, demote = plan(st)
-                tier, pexec, dexec, mig_up, mig_down = \
-                    interval_ops.tier_migrate(tier0, promote, demote, caps)
-                waste, p_at, d_at = jax.vmap(
-                    simjax.wasteful_update,
-                    in_axes=(None, 0, 0, 0, 0, 0, 0))(
-                    t - 1, p_at0, d_at0, promote, demote, pexec, dexec)
-                return (st, tier, p_at, d_at,
-                        pexec.sum(axis=1).astype(jnp.int32),
-                        dexec.sum(axis=1).astype(jnp.int32), waste,
-                        mig_up, mig_down)
-
-            def skip(op):
-                st, tier0, p_at0, d_at0 = op
-                z = jnp.zeros((B,), jnp.int32)
-                zp = jnp.zeros((B, R - 1), jnp.int32)
-                return st, tier0, p_at0, d_at0, z, z, z, zp, zp
+                with jax.named_scope("policy"):
+                    st, promote, demote = plan(st)
+                with jax.named_scope("migrate"):
+                    tier, pexec, dexec, mig_up, mig_down = \
+                        interval_ops.tier_migrate(tier0, promote, demote,
+                                                  caps)
+                    waste, p_at, d_at = jax.vmap(
+                        simjax.wasteful_update,
+                        in_axes=(None, 0, 0, 0, 0, 0, 0))(
+                        t - 1, p_at0, d_at0, promote, demote, pexec, dexec)
+                    return (st, tier, p_at, d_at,
+                            pexec.sum(axis=1).astype(jnp.int32),
+                            dexec.sum(axis=1).astype(jnp.int32), waste,
+                            mig_up, mig_down)
 
             (state, tier, promoted_at, demoted_at, n_promo, n_demo, waste,
              mig_up, mig_down) = jax.lax.cond(
-                jnp.any(do), fire, skip,
+                any_do, fire, skip_moves,
                 (state, c["tier"], c["promoted_at"], c["demoted_at"]))
-            acc_fast, acc_slow, wall, slow_share, app_raw, recall = \
-                interval_ops.interval_account(
-                    mach, true_b, tier, mig_up.astype(f32),
-                    mig_down.astype(f32), orc_b, k)
+            with jax.named_scope("account"):
+                acc_fast, acc_slow, wall, slow_share, app_raw, recall = \
+                    interval_ops.interval_account(
+                        mach, true_b, tier, mig_up.astype(f32),
+                        mig_down.astype(f32), orc_b, k)
         else:
             def fire(st):
-                return plan(st)
+                with jax.named_scope("policy"):
+                    return plan(st)
 
             def skip(st):
                 return (st, jnp.full((B, pad_p), -1, jnp.int32),
@@ -493,70 +500,76 @@ def _simulate(spec, trace, oracle_mask, k: int, mach, caps, keys, sample,
             # dominates its cost) only runs on intervals where at least one
             # lane's cadence is due — unlike an outer vmap-of-cond, which
             # would select-execute it every interval.
-            state, promote, demote = jax.lax.cond(jnp.any(do), fire, skip,
-                                                  state)
+            state, promote, demote = jax.lax.cond(any_do, fire, skip, state)
 
-            tier, pexec, dexec, mig_up, mig_down = jax.vmap(
-                simjax.apply_tier_migrations, in_axes=(0, 0, 0, 0))(
-                c["tier"], promote, demote, caps)
-            n_promo = pexec.sum(axis=1).astype(jnp.int32)       # [B]
-            n_demo = dexec.sum(axis=1).astype(jnp.int32)
-            waste, promoted_at, demoted_at = jax.vmap(
-                simjax.wasteful_update, in_axes=(None, 0, 0, 0, 0, 0, 0))(
-                t - 1, c["promoted_at"], c["demoted_at"], promote, demote,
-                pexec, dexec)
-            acc_fast, acc_slow, wall, slow_share, app_raw = _per_lane(
-                simjax.interval_accounting_impl,
-                mach, true_b, tier, mig_up.astype(f32),
-                mig_down.astype(f32))
-            recall = ((tier == 0) & orc_b).sum(axis=1).astype(f32) / k
-        if cls.mixed_observation:
-            # per-lane mechanism overhead (union lanes): non-TPP lanes
-            # carry 0.0, and ``wall + acc_slow * 0.0 * 1e-9 / mlp`` adds
-            # +0.0 to a nonnegative finite wall — a bitwise no-op.
-            extra = jax.vmap(cls.slow_extra_lane)(spec)          # [B]
-            wall = wall + acc_slow * extra * f32(1e-9) / mach.mlp
-        elif cls.slow_access_extra_ns:
-            # policy-mechanism overhead charged to the application (TPP's
-            # NUMA hint faults are taken on slow-tier accesses).
-            wall = wall + acc_slow * f32(cls.slow_access_extra_ns) \
-                * f32(1e-9) / mach.mlp
+            with jax.named_scope("migrate"):
+                tier, pexec, dexec, mig_up, mig_down = jax.vmap(
+                    simjax.apply_tier_migrations, in_axes=(0, 0, 0, 0))(
+                    c["tier"], promote, demote, caps)
+                n_promo = pexec.sum(axis=1).astype(jnp.int32)   # [B]
+                n_demo = dexec.sum(axis=1).astype(jnp.int32)
+                waste, promoted_at, demoted_at = jax.vmap(
+                    simjax.wasteful_update,
+                    in_axes=(None, 0, 0, 0, 0, 0, 0))(
+                    t - 1, c["promoted_at"], c["demoted_at"], promote,
+                    demote, pexec, dexec)
+            with jax.named_scope("account"):
+                acc_fast, acc_slow, wall, slow_share, app_raw = _per_lane(
+                    simjax.interval_accounting_impl,
+                    mach, true_b, tier, mig_up.astype(f32),
+                    mig_down.astype(f32))
+                recall = ((tier == 0) & orc_b).sum(axis=1).astype(f32) / k
+        with jax.named_scope("policy"):
+            mode = vmode(spec, state)
+        with jax.named_scope("account"):
+            if cls.mixed_observation:
+                # per-lane mechanism overhead (union lanes): non-TPP lanes
+                # carry 0.0, and ``wall + acc_slow * 0.0 * 1e-9 / mlp``
+                # adds +0.0 to a nonnegative finite wall — a bitwise no-op.
+                extra = jax.vmap(cls.slow_extra_lane)(spec)      # [B]
+                wall = wall + acc_slow * extra * f32(1e-9) / mach.mlp
+            elif cls.slow_access_extra_ns:
+                # policy-mechanism overhead charged to the application
+                # (TPP's NUMA hint faults are taken on slow-tier accesses).
+                wall = wall + acc_slow * f32(cls.slow_access_extra_ns) \
+                    * f32(1e-9) / mach.mlp
 
-        new_c = dict(
-            state=state, tier=tier,
-            promoted_at=promoted_at, demoted_at=demoted_at, t=t, key=key,
-            slow_bw=slow_share,
-            # consumer-side clamp of the RAW tier-0 utilization: the
-            # policy-facing signal stays in [0,1] (bitwise the historical
-            # at-source clamp; the raw ratio keeps oversaturation visible
-            # to accounting consumers).
-            app_bw=jnp.minimum(1.0, app_raw),
-            exec_time=c["exec_time"] + wall,
-            promotions=c["promotions"] + n_promo,
-            demotions=c["demotions"] + n_demo,
-            wasteful=c["wasteful"] + waste,
-            acc_fast_total=c["acc_fast_total"] + acc_fast,
-            acc_total=c["acc_total"] + acc_fast + acc_slow,
-            recall_sum=c["recall_sum"] + recall)
-        if tn:
-            new_c["tier_util"] = _per_lane(
-                simjax.tier_utilization_impl,
-                mach, true_b, tier, mig_up.astype(f32),
-                mig_down.astype(f32))
-        if wl is not None:
-            new_c["wl_state"] = wst
-        hits_val = acc_fast / jnp.maximum(acc_fast + acc_slow, 1e-9)
-        if reduce == "stream":
-            # per-interval outputs folded into the carry: the scan emits no
-            # ys, so nothing [T, ...]-shaped is ever allocated.
-            new_c["slow_sum"] = c["slow_sum"] + slow_share
-            new_c["hits_sum"] = c["hits_sum"] + hits_val
-            new_c["mode_sum"] = c["mode_sum"] + vmode(spec, state)
-            new_c["promos_max"] = jnp.maximum(c["promos_max"], n_promo)
-            ys = {}
-        else:
-            ys = dict(slow=slow_share, hits=hits_val,
-                      mode=vmode(spec, state), promos=n_promo)
+            new_c = dict(
+                state=state, tier=tier,
+                promoted_at=promoted_at, demoted_at=demoted_at, t=t,
+                key=key, slow_bw=slow_share,
+                # consumer-side clamp of the RAW tier-0 utilization: the
+                # policy-facing signal stays in [0,1] (bitwise the
+                # historical at-source clamp; the raw ratio keeps
+                # oversaturation visible to accounting consumers).
+                app_bw=jnp.minimum(1.0, app_raw),
+                exec_time=c["exec_time"] + wall,
+                promotions=c["promotions"] + n_promo,
+                demotions=c["demotions"] + n_demo,
+                wasteful=c["wasteful"] + waste,
+                acc_fast_total=c["acc_fast_total"] + acc_fast,
+                acc_total=c["acc_total"] + acc_fast + acc_slow,
+                recall_sum=c["recall_sum"] + recall)
+            if tn:
+                new_c["tier_util"] = _per_lane(
+                    simjax.tier_utilization_impl,
+                    mach, true_b, tier, mig_up.astype(f32),
+                    mig_down.astype(f32))
+            if wl is not None:
+                new_c["wl_state"] = wst
+            hits_val = acc_fast / jnp.maximum(acc_fast + acc_slow, 1e-9)
+            if reduce == "stream":
+                # per-interval outputs folded into the carry: the scan
+                # emits no ys, so nothing [T, ...]-shaped is ever
+                # allocated.
+                new_c["slow_sum"] = c["slow_sum"] + slow_share
+                new_c["hits_sum"] = c["hits_sum"] + hits_val
+                new_c["mode_sum"] = c["mode_sum"] + mode
+                new_c["promos_max"] = jnp.maximum(c["promos_max"], n_promo)
+                ys = {}
+            else:
+                ys = dict(slow=slow_share, hits=hits_val, mode=mode,
+                          promos=n_promo)
         return new_c, ys
 
     carry = _init_carry(spec, B, n, k, mach, keys)
@@ -705,8 +718,6 @@ def _timelines_lane_major(out):
 
 
 def _record_dispatch(**info):
-    global dispatch_count
-    dispatch_count += 1
     if "T" in info and "lanes" in info:
         # lanes x intervals: the dispatch's compute spend in the unit the
         # search engine compares strategies on (SearchResult.lane_intervals).

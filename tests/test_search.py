@@ -2,8 +2,8 @@
 elimination, deterministic ranking, and the machine-transfer matrix.
 
 The load-bearing properties: (1) every search round is ONE compiled
-dispatch per policy family (asserted via ``scan_engine.dispatch_count``
-deltas); (2) ASHA with ``eta=1`` degenerates to exhaustive grid search
+dispatch per policy family (asserted via ``scan_engine.count_dispatches``);
+(2) ASHA with ``eta=1`` degenerates to exhaustive grid search
 BITWISE — same configs, same scores, same ranking — because both paths
 evaluate the same population in the same lanes under the same CRN field;
 (3) survivors are always drawn from the previous round's population;
