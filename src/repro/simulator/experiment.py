@@ -246,6 +246,7 @@ def sweep(policies, *, workloads=None, trace=None, machines="pmem-large",
             W = len(wl_specs)
             wl = scan_engine._stack_workloads(wl_specs)
             wl_boost = any(w.has_boost() for w in wl_specs)
+            wl_drift = any(w.has_drift() for w in wl_specs)
         else:
             if trace is None:
                 raise ValueError("need a trace or a workloads list")
@@ -323,7 +324,7 @@ def sweep(policies, *, workloads=None, trace=None, machines="pmem-large",
                     jnp.stack([jax.random.PRNGKey(wl_seed)] * W),
                     sampling,
                     scan_engine._synth_need_normal(wl_specs, min_period),
-                    Pg * M * S, n, wl_boost=wl_boost,
+                    Pg * M * S, n, wl_boost=wl_boost, wl_drift=wl_drift,
                     interval_kernel=use_interval_kernel, reduce=reduce,
                     mesh=mesh, pad_multiple=_pad_multiple)
             else:

@@ -415,12 +415,12 @@ def _fab_trace_jit(spec, trace, oracle_mask, k, mach, caps, keys, sample,
 
 @functools.partial(
     jax.jit, static_argnames=("k", "sampling", "need_normal", "n",
-                              "wl_boost", "interval_kernel", "reduce",
-                              "tier_shim", "mesh"),
+                              "wl_boost", "wl_drift", "interval_kernel",
+                              "reduce", "tier_shim", "mesh"),
     donate_argnums=(0, 3, 4, 5, 9))
 def _fab_synth_jit(spec, wl, k, mach, caps, keys, sample, noise_key,
                    wl_keys, widx, sampling, need_normal, n, wl_boost,
-                   interval_kernel, reduce, tier_shim, mesh):
+                   wl_drift, interval_kernel, reduce, tier_shim, mesh):
     # NB mirrors _sim_synth_jit's donation: wl / sample are shared across
     # dispatches (CRN pairing) and never donated; widx (9) is rebuilt per
     # call and is.
@@ -429,7 +429,7 @@ def _fab_synth_jit(spec, wl, k, mach, caps, keys, sample, noise_key,
         lambda sp, w, mc, cp, ky, sm, nk, wk, wi: scan_engine._simulate(
             sp, None, None, k, mc, cp, ky, sm, sampling, need_normal,
             wl=w, wl_keys=wk, noise_key=nk, n=n, wl_boost=wl_boost,
-            interval_kernel=interval_kernel, reduce=reduce,
+            wl_drift=wl_drift, interval_kernel=interval_kernel, reduce=reduce,
             tier_shim=tier_shim, widx=wi),
         mesh=mesh,
         in_specs=(lane, rep, lane, lane, lane, rep, rep, rep, lane),
@@ -468,8 +468,8 @@ def sim_trace(spec, trace, oracle_mask, k, mach, caps, keys, sample,
 
 def sim_synth(spec, wl, k, mach, caps, keys, sample, noise_key, wl_keys,
               sampling, need_normal, wl_rep, n, wl_boost=True,
-              interval_kernel=True, reduce="stack", tier_shim=False,
-              mesh=None, pad_multiple=None):
+              wl_drift=True, interval_kernel=True, reduce="stack",
+              tier_shim=False, mesh=None, pad_multiple=None):
     """Synth-mode dispatch, optionally sharded (see ``sim_trace``).
 
     ``wl_rep`` maps lane -> workload exactly as in ``_sim_synth_jit``
@@ -482,7 +482,7 @@ def sim_synth(spec, wl, k, mach, caps, keys, sample, noise_key, wl_keys,
         out = scan_engine._sim_synth_jit(
             spec, wl, k, mach, caps, keys, sample, noise_key, wl_keys,
             sampling, need_normal, wl_rep, n, wl_boost=wl_boost,
-            interval_kernel=interval_kernel, reduce=reduce,
+            wl_drift=wl_drift, interval_kernel=interval_kernel, reduce=reduce,
             tier_shim=tier_shim)
         return out, {}
     D = D or 1
@@ -495,5 +495,6 @@ def sim_synth(spec, wl, k, mach, caps, keys, sample, noise_key, wl_keys,
         _pad_lanes(x, B, Lp) for x in (spec, mach, caps, keys))
     out = _fab_synth_jit(spec, wl, k, mach, caps, keys, sample, noise_key,
                          wl_keys, widx, sampling, need_normal, n, wl_boost,
-                         interval_kernel, reduce, tier_shim, _lane_mesh(D))
+                         wl_drift, interval_kernel, reduce, tier_shim,
+                         _lane_mesh(D))
     return _unpad_out(out, B), dict(mesh=D, padded_lanes=Lp)

@@ -230,8 +230,9 @@ def _init_carry(spec, B: int, n: int, k: int, mach, keys):
 def _simulate(spec, trace, oracle_mask, k: int, mach, caps, keys, sample,
               sampling: str, need_normal: bool, wl=None, wl_keys=None,
               noise_key=None, wl_rep: int = 1, n: int | None = None,
-              wl_boost: bool = True, interval_kernel: bool = True,
-              reduce: str = "stack", tier_shim: bool = False, widx=None):
+              wl_boost: bool = True, wl_drift: bool = True,
+              interval_kernel: bool = True, reduce: str = "stack",
+              tier_shim: bool = False, widx=None):
     """Traceable batched replay; returns a dict of [B] scalars + timelines.
 
     Lanes (= sweep entries) form the leading axis of every carried array,
@@ -363,8 +364,9 @@ def _simulate(spec, trace, oracle_mask, k: int, mach, caps, keys, sample,
                         lambda w, st_: wl_cls.event(w, st_, tw, wl_boost))(
                         wl, s),
                     lambda s: s, wst)
-                probs = jax.vmap(wl_cls.probs_of, in_axes=(0, 0, None))(
-                    wl, wst, tw)                                 # [W, n]
+                probs = jax.vmap(
+                    lambda w, st_: wl_cls.probs_of(w, st_, tw, wl_drift))(
+                    wl, wst)                                     # [W, n]
                 workt = jax.vmap(wl_cls.work_of, in_axes=(0, 0, None))(
                     wl, wst, tw)                                 # [W]
                 true_w = workt[:, None] * probs
@@ -657,21 +659,22 @@ def _sim_pre_jit(spec, trace, oracle_mask, k, mach, caps, keys, u, periods,
 
 @functools.partial(
     jax.jit, static_argnames=("k", "sampling", "need_normal",
-                              "wl_rep", "n", "wl_boost",
+                              "wl_rep", "n", "wl_boost", "wl_drift",
                               "interval_kernel", "reduce", "tier_shim"),
     donate_argnums=(0, 3, 4, 5, 7, 8))
 def _sim_synth_jit(spec, wl, k, mach, caps, keys, sample, noise_key,
                    wl_keys, sampling, need_normal, wl_rep, n,
-                   wl_boost=True, interval_kernel=True, reduce="stack",
-                   tier_shim=False):
+                   wl_boost=True, wl_drift=True, interval_kernel=True,
+                   reduce="stack", tier_shim=False):
     # NB: ``wl`` (position 1) and ``sample`` (6) are NOT donated —
     # experiment.sweep shares one workload stack / CRN field across every
     # per-family dispatch of a single axis-product call.
     return _simulate(spec, None, None, k, mach, caps, keys, sample,
                      sampling, need_normal, wl=wl, wl_keys=wl_keys,
                      noise_key=noise_key, wl_rep=wl_rep, n=n,
-                     wl_boost=wl_boost, interval_kernel=interval_kernel,
-                     reduce=reduce, tier_shim=tier_shim)
+                     wl_boost=wl_boost, wl_drift=wl_drift,
+                     interval_kernel=interval_kernel, reduce=reduce,
+                     tier_shim=tier_shim)
 
 
 def _synth_need_normal(wl_specs, min_period: float) -> bool:
@@ -929,7 +932,7 @@ def simulate_workload(spec, workload, machine, k: int, T: int, n: int,
         jax.random.PRNGKey(0)[None], sample, jax.random.PRNGKey(sim_seed),
         jax.random.PRNGKey(wl_seed)[None], "crn" if crn else "crn_prng",
         _synth_need_normal([workload], spec.min_sampling_period()), 1, n,
-        wl_boost=workload.has_boost(),
+        wl_boost=workload.has_boost(), wl_drift=workload.has_drift(),
         interval_kernel=use_interval_kernel)
     _record_dispatch(lanes=1, sampling="crn" if crn else "crn_prng",
                      policy=spec.name, synth=True, workloads=1, configs=1,
@@ -968,7 +971,8 @@ def sweep_workloads(workloads, machine, k: int, T: int, n: int,
         jnp.zeros((T, 1), jnp.float32), jax.random.PRNGKey(sim_seed),
         jnp.stack([jax.random.PRNGKey(wl_seed)] * W), "crn_prng",
         _synth_need_normal(workloads, spec.min_sampling_period()), 1, n,
-        wl_boost=any(w.has_boost() for w in workloads))
+        wl_boost=any(w.has_boost() for w in workloads),
+        wl_drift=any(w.has_drift() for w in workloads))
     _record_dispatch(lanes=W, sampling="crn_prng", policy=spec.name,
                      synth=True, workloads=W, configs=1, machines=1, T=T,
                      interval_kernel=True, reduce="stack")
@@ -1016,7 +1020,8 @@ def sweep_workload_configs(spec_family, configs, workloads, machine, k: int,
         jnp.stack([jax.random.PRNGKey(wl_seed)] * W),
         "crn" if crn else "crn_prng",
         _synth_need_normal(workloads, min_period), B, n,
-        wl_boost=any(w.has_boost() for w in workloads))
+        wl_boost=any(w.has_boost() for w in workloads),
+        wl_drift=any(w.has_drift() for w in workloads))
     _record_dispatch(lanes=W * B, sampling="crn" if crn else "crn_prng",
                      policy=pol_specs[0].name, synth=True, workloads=W,
                      configs=B, machines=1, T=T, interval_kernel=True,

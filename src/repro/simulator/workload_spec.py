@@ -172,8 +172,14 @@ class WorkloadSpec:
         m = jnp.where(busy, f32(1.0), self.idle_scale)
         return self.weight * active * self.work * m
 
-    def _comp_probs(self, state, t):
-        """f32 [S, n] per-component normalized access distributions."""
+    def _comp_probs(self, state, t, with_drift: bool = True):
+        """f32 [S, n] per-component normalized access distributions.
+
+        ``with_drift`` is a STATIC flag (see ``has_drift``): when no
+        component drifts, the shift is 0 at every interval and the rank
+        rows are read as they are; otherwise they are rotated by the
+        shift (``rotate``).  Both give the same ranks, bit for bit.
+        """
         f32 = jnp.float32
         tf = jnp.asarray(t, f32)
 
@@ -182,10 +188,15 @@ class WorkloadSpec:
             n = rank.shape[0]
             nf = f32(n)
             i = jnp.arange(n, dtype=jnp.int32)
-            shift = jnp.floor(drift_rate * tf).astype(jnp.int32) % n
-            idx = (i - shift) % n
-            r = rank[idx].astype(f32)
-            r2 = rank2[idx].astype(f32)
+            if with_drift:
+                shift = jnp.floor(drift_rate * tf).astype(jnp.int32) % n
+                idx = (i - shift) % n
+                with jax.named_scope("rotate"):
+                    rank, rank2 = rotate(jnp.stack([rank, rank2]), shift)
+            else:
+                idx = i
+            r = rank.astype(f32)
+            r2 = rank2.astype(f32)
 
             def zipf(_):
                 return (r + 1.0) ** (-s)
@@ -232,9 +243,10 @@ class WorkloadSpec:
                              self.boost_gain, self.drift_rate,
                              state.rank, state.rank2)
 
-    def probs_of(self, state, t):
-        """f32 [n] interval access distribution (sums to 1 to f32 tol)."""
-        p = self._comp_probs(state, t)                       # [S, n]
+    def probs_of(self, state, t, with_drift: bool = True):
+        """f32 [n] interval access distribution (sums to 1 to f32 tol).
+        ``with_drift`` is static, as in ``_comp_probs``."""
+        p = self._comp_probs(state, t, with_drift)           # [S, n]
         rate = self._rates(t)                                # [S]
         tot = rate.sum()
         mix = (rate[:, None] * p).sum(axis=0) / jnp.maximum(tot, 1e-30)
@@ -268,6 +280,11 @@ class WorkloadSpec:
         the engines statically skip the second permutation draw."""
         return bool(np.any(np.asarray(self.boost_every) < NEVER))
 
+    def has_drift(self) -> bool:
+        """Host-side: can any component's drift shift be non-zero?  Lets
+        the engines statically read the rank rows without rotating them."""
+        return bool(np.any(np.asarray(self.drift_rate) != 0.0))
+
     def materialize(self, T: int, n: int, seed: int = 0) -> np.ndarray:
         """Dense f32 ``[T, n]`` trace for the numpy reference engine.
 
@@ -278,21 +295,39 @@ class WorkloadSpec:
         global MATERIALIZE_CALLS
         MATERIALIZE_CALLS += 1
         tr = _materialize_jit(self, T, n, jax.random.PRNGKey(seed),
-                              self.has_boost())
+                              self.has_boost(), self.has_drift())
         return np.asarray(tr)
 
 
-@functools.partial(jax.jit, static_argnames=("T", "n", "with_boost"))
-def _materialize_jit(spec, T, n, key, with_boost):
+def rotate(x, shift):
+    """``x[..., (arange(n) - shift) % n]`` for a traced scalar ``shift``
+    in ``[0, n)``, without a gather.
+
+    A gather indexed by a traced shift (``jnp.roll`` included, whose
+    batched ``dynamic_slice`` lowers to one) reads element by element.
+    Here the rotation is a barrel shift of ``log2(n)`` stages: stage
+    ``b`` is a static roll by ``2**b`` (a slice and a concatenate), kept
+    where bit ``b`` of ``shift`` is set.
+    """
+    for b in range((x.shape[-1] - 1).bit_length()):
+        x = jnp.where((shift >> b) & 1 == 1, jnp.roll(x, 1 << b, axis=-1),
+                      x)
+    return x
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("T", "n", "with_boost", "with_drift"))
+def _materialize_jit(spec, T, n, key, with_boost, with_drift):
     cls = type(spec)
 
     def row(st, t):
         # same cond + split functions the scan engine inlines (step's
-        # reference composition, with the static boost-draw flag)
+        # reference composition, with the static boost-draw and drift
+        # flags)
         st = jax.lax.cond(cls.event_due(spec, st, t),
                           lambda s: cls.event(spec, s, t, with_boost),
                           lambda s: s, st)
-        probs = cls.probs_of(spec, st, t)
+        probs = cls.probs_of(spec, st, t, with_drift)
         return st, cls.work_of(spec, st, t) * probs
 
     _, tr = jax.lax.scan(row, spec.init(n, key), jnp.arange(T))

@@ -7,6 +7,8 @@ BITWISE identical to replaying the host-materialized ``[T, n]`` f32 trace
 (same CRN noise), and the numpy reference engine on that trace must agree
 exactly on migration counts.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,13 +17,32 @@ from _hypothesis_fallback import given, settings, st
 
 from repro.baselines.arms_policy import ARMSSpec
 from repro.baselines.hemem import HeMemPolicy, HeMemSpec
-from repro.simulator import scan_engine, tuning, workload_spec, workloads
+from repro.simulator import (experiment, scan_engine, scenarios, tuning,
+                             workload_spec, workloads)
 from repro.simulator.engine import oracle_topk_masks, run
 from repro.simulator.machine import PMEM_LARGE
 from repro.simulator.sampling import synth_noise_field
 
 T, N, K = 96, 256, 32
 NAMES = list(workload_spec.NAMED_WORKLOADS)
+#: drifting workloads: the rank rows are rotated every interval
+DRIFTING = {
+    "drifting_hot": lambda: scenarios.drifting_hot(N, K, rate=5.0),
+    "drift(xsbench,3)": lambda: workload_spec.drift(
+        workload_spec.named("xsbench"), 3.0),
+}
+
+
+def _workload(name):
+    return DRIFTING[name]() if name in DRIFTING else \
+        workload_spec.named(name, T=T)
+
+
+def _indexed(x, shift):
+    """The drift's page index written out: ``x[..., (arange(n) - shift)
+    % n]``, the oracle of ``workload_spec.rotate``."""
+    n = x.shape[-1]
+    return x[..., (jnp.arange(n, dtype=jnp.int32) - shift) % n]
 
 
 @jax.jit
@@ -175,9 +196,9 @@ class TestSynthMaterializeEquivalence:
     """The acceptance bar: synth == materialized replay, bitwise, for every
     named workload — in the scan engine and against the numpy engine."""
 
-    @pytest.mark.parametrize("name", NAMES)
-    def test_bitwise_across_paths(self, name):
-        wl = workload_spec.named(name, T=T)
+    @pytest.mark.parametrize("name", NAMES + list(DRIFTING))
+    def test_bitwise_across_paths(self, name, monkeypatch):
+        wl = _workload(name)
         u = synth_noise_field(T, N, seed=7)
         synth = scan_engine.simulate_workload(
             HeMemSpec.make(), wl, PMEM_LARGE, K, T, N, sim_seed=7)
@@ -201,6 +222,16 @@ class TestSynthMaterializeEquivalence:
             (ref.promotions, ref.demotions, ref.wasteful)
         np.testing.assert_allclose(synth.exec_time_s, ref.exec_time_s,
                                    rtol=1e-4)
+        if name in DRIFTING:
+            # the rotated rows against the indexed formula: the same trace,
+            # bit for bit, from a fresh compile with the gather put back
+            monkeypatch.setattr(workload_spec, "rotate", _indexed)
+            indexed = jax.jit(workload_spec._materialize_jit.__wrapped__,
+                              static_argnames=("T", "n", "with_boost",
+                                               "with_drift"))
+            want = indexed(wl, T, N, jax.random.PRNGKey(0), wl.has_boost(),
+                           wl.has_drift())
+            np.testing.assert_array_equal(trace, np.asarray(want))
 
     def test_arms_synth_matches_materialized(self):
         wl = workload_spec.named("gups", T=T)
@@ -289,3 +320,78 @@ class TestWorkloadSweeps:
                         workloads=["gups"], T=4, n=8)
         with pytest.raises(ValueError):
             tuning.tune("hemem", None, PMEM_LARGE, 2, workloads=["gups"])
+
+
+class TestRankRotation:
+    """The rank rows at the drift shift: read as they are where nothing
+    drifts, rotated gather-free where something does; both bitwise the
+    indexed formula ``rank[(arange(n) - shift) % n]``."""
+
+    @pytest.mark.parametrize("n", [1, 2, 3, 7, 64, 65, 256, 1000, 4096,
+                                   65536])
+    def test_rotate_matches_indexed_formula(self, n):
+        rng = np.random.default_rng(n)
+        rank = jnp.asarray(np.stack([np.stack([rng.permutation(n)
+                                               for _ in range(2)])
+                                     for _ in range(3)]), jnp.int32)
+        # shifts 0, 1, n - 1, a random draw, and rates past n
+        rate = jnp.asarray([[0.0, 1.0], [n - 1.0, rng.uniform(0, n)],
+                            [n + 3.5, 2.5 * n + 1.0]], jnp.float32)
+        t = jnp.int32(1)
+
+        def shift_of(r):       # the drift shift of _comp_probs
+            return jnp.floor(r * t.astype(jnp.float32)).astype(
+                jnp.int32) % n
+
+        got = jax.vmap(jax.vmap(
+            lambda row, r: workload_spec.rotate(row, shift_of(r))))(
+            rank, rate)
+        want = jax.vmap(jax.vmap(
+            lambda row, r: _indexed(row, shift_of(r))))(rank, rate)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        shifts = np.asarray(jax.vmap(jax.vmap(shift_of))(rate))
+        if n > 2:
+            assert {0, 1, n - 1} <= set(shifts.ravel().tolist())
+
+    @pytest.mark.parametrize("name", NAMES)
+    def test_still_rows_read_directly(self, name):
+        spec = workload_spec.named(name, T=60)
+        assert not spec.has_drift()
+        assert workload_spec.drift(spec, 3.0).has_drift()
+        state = spec.init(128, jax.random.PRNGKey(0))
+        for t in (0, 7, 59):
+            t = jnp.int32(t)
+            np.testing.assert_array_equal(
+                np.asarray(spec.probs_of(state, t, with_drift=False)),
+                np.asarray(spec.probs_of(state, t, with_drift=True)))
+
+    @pytest.mark.parametrize("drifting", [False, True])
+    def test_program_gathers_no_rank_rows(self, monkeypatch, drifting):
+        """The compiled sweep program holds no gather over the ``[W, S,
+        n]`` rank stack; where a lane drifts, the rotation carries the
+        ``synth/rotate`` scope."""
+        n = 256
+        wls = ["gups", "btree",
+               scenarios.drifting_hot(n, 32) if drifting else "xsbench"]
+        calls, jitted = [], scan_engine._sim_synth_jit
+
+        def record(*a, **kw):
+            calls.append((a, kw))
+            return jitted(*a, **kw)
+
+        monkeypatch.setattr(scan_engine, "_sim_synth_jit", record)
+        experiment.sweep(["arms"], workloads=wls, machines=["pmem-large"],
+                         k=32, T=4, n=n)
+        (a, kw), = calls
+        assert kw["wl_drift"] is drifting
+        text = jitted.lower(*a, **kw).compile().as_text()
+        shape = {m.group(1): m.group(2) for m in re.finditer(
+            r"%(\S+) = \w+\[([\d,]*)\]", text)}
+        gathers = re.findall(r" gather\(%(\S+?),.*op_name=\"([^\"]*)\"",
+                             text)
+        assert gathers           # the policies' own row reads are there
+        for operand, op_name in gathers:
+            assert shape[operand] != f"3,2,{n}", operand
+            assert "/synth/" not in op_name, op_name
+        rotate = re.findall(r'op_name="[^"]*/synth/[^"]*rotate\)*/', text)
+        assert bool(rotate) is drifting
