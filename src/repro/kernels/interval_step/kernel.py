@@ -1,14 +1,15 @@
 """Pallas TPU kernels: fused per-interval fast path of the scan engine.
 
-Four kernels, one grid step per sweep lane (grid = (B,)), each fusing a
-stage of scan_engine's interval body that the unfused path spreads over
-many small XLA ops:
+Four kernels over lane-batched page rows, each fusing a stage of
+scan_engine's interval body that the unfused path spreads over many
+small XLA ops:
 
   * ``topk_mask_kernel``    — exact top-k mask by threshold bisection over
-    the uint32 order key (32 count-passes) plus an index bisection for the
-    tie-break (no ``lax.top_k`` partial sort, no scatter, no cumsum — the
-    tie rule still matches ``lax.top_k`` exactly: strictly-greater first,
-    ascending index among threshold-equal values);
+    the order key (32 count-passes) and, in the one block that holds the
+    boundary, an index bisection for the tie-break (no ``lax.top_k``
+    partial sort, no scatter, no cumsum — the tie rule still matches
+    ``lax.top_k`` exactly: strictly-greater first, ascending index among
+    threshold-equal values);
   * ``tier_migrate_kernel`` — the adjacent-pair hop-chain migration engine
     as a per-lane sequential sweep over the padded plans with per-tier
     occupancy counters (equivalent to the vectorized simjax form for
@@ -21,9 +22,17 @@ many small XLA ops:
 
 Layout (the Mosaic rules every block obeys at any B and n):
 
-  * a lane's [n] page row is padded to whole 128-wide rows and blocked as
-    ``(n_pad // 128, 128)`` of a ``[B, n_pad // 128, 128]`` array, so the
-    block's last two dims equal the array's;
+  * a lane's [n] page row is padded to whole 128-wide rows and walked in
+    page blocks of ``(rows, 128)`` (``_blocks``): one block of all its
+    rows while they fit ``BLOCK_ROWS``, so the block's last two dims
+    equal the array's, else the fewest blocks of at most ``BLOCK_ROWS``
+    rows, whole 8-row tiles each, streamed from HBM.  A row of one block
+    is the many-block case with one block: the grid is ``(B, blocks)``
+    (top-k: ``(B, sweeps, blocks)``) either way;
+  * what a kernel carries across a row's blocks lives in scratch: the
+    accounting's partial sums in VMEM, the top-k bisection's threshold
+    and counts in SMEM; outputs are written at the block that completes
+    them;
   * per-lane scalars (machine rows, caps, EWMA weights) and scalar
     outputs live in SMEM as one ``(1, w)`` row per lane — VMEM takes no
     scalar stores and no dynamically indexed scalar reads;
@@ -41,6 +50,7 @@ consistently.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -56,21 +66,73 @@ LANE = 128          # f32 / i32 minor-dim tile
 #: compiler.  tests/test_tpu_compile.py compiles the kernel for v5e at
 #: exactly this footprint; other TPU generations are not checked.
 SMEM_WORDS = 3 << 16
+#: Page rows (of LANE pages) of a lane's row that the top-k, accounting
+#: and EWMA kernels hold in one VMEM block; a longer row is walked in
+#: blocks of at most this many.  The widest, EWMA, double-buffers six
+#: such f32 blocks: 6 MiB, inside v5e's default scoped VMEM (16 MiB) at
+#: any n (tests/test_tpu_compile.py compiles all three at 2**21 pages).
+#: 1024 rows keep the 65536-page rows of the benchmark's cells in one.
+BLOCK_ROWS = 1024
 
 
 def _padded(n: int) -> int:
     return max(LANE, -(-n // LANE) * LANE)
 
 
-def _tiles(x, fill):
-    """[B, n] -> [B, n_pad // LANE, LANE] (padding filled with ``fill``)."""
+def _blocks(n: int) -> tuple[int, int]:
+    """(blocks, rows per block) a lane's [n] page row is walked in: all
+    its rows in one block while they fit ``BLOCK_ROWS``, else the fewest
+    blocks of at most ``BLOCK_ROWS`` rows, whole 8-row tiles each."""
+    rows = _padded(n) // LANE
+    if rows <= BLOCK_ROWS:
+        return 1, rows
+    nb = -(-rows // BLOCK_ROWS)
+    return nb, -(-rows // (8 * nb)) * 8
+
+
+def _tiles(x, fill, rows: int):
+    """[B, n] -> [B, rows, LANE] (padding filled with ``fill``)."""
     B, n = x.shape
-    x = jnp.pad(x, ((0, 0), (0, _padded(n) - n)), constant_values=fill)
-    return x.reshape(B, -1, LANE)
+    x = jnp.pad(x, ((0, 0), (0, rows * LANE - n)), constant_values=fill)
+    return x.reshape(B, rows, LANE)
 
 
 def _untile(x, n: int):
     return x.reshape(x.shape[0], -1)[:, :n]
+
+
+#: open ``count_blocks`` tallies (nesting is fine: each sees every trace)
+_block_counters: list = []
+
+
+@contextlib.contextmanager
+def count_blocks():
+    """Page blocks per row that each blocked kernel is traced with inside
+    the region, in trace order:
+
+        with kernel.count_blocks() as blocks:
+            experiment.sweep(...)
+        assert blocks["topk_mask_kernel"] == [16]
+
+    A call that hits JAX's caches traces nothing and records nothing."""
+    rec: dict = {}
+    _block_counters.append(rec)
+    try:
+        yield rec
+    finally:
+        _block_counters.remove(rec)
+
+
+def _record_blocks(kernel: str, n: int) -> tuple[int, int]:
+    nb, br = _blocks(n)
+    for rec in _block_counters:
+        rec.setdefault(kernel, []).append(nb)
+    return nb, br
+
+
+def _row_block(br: int):
+    """Block ``j`` of lane ``b``'s row, on a ``(B, blocks)`` grid."""
+    return pl.BlockSpec((None, br, LANE), lambda b, j: (b, j, 0))
 
 
 def _lane_block(x):
@@ -81,8 +143,8 @@ def _lane_block(x):
 
 def _lane_smem(w: int):
     """One lane's ``(1, w)`` row of a ``[B, 1, w]`` per-lane scalar array,
-    in SMEM."""
-    return pl.BlockSpec((None, 1, w), lambda b: (b, 0, 0),
+    in SMEM (on a grid whose first axis is the lane)."""
+    return pl.BlockSpec((None, 1, w), lambda b, *_: (b, 0, 0),
                         memory_space=pltpu.SMEM)
 
 
@@ -94,49 +156,107 @@ def _page_iota(shape):
 
 
 # ------------------------------------------------------------ top-k mask
-def _topk_body(n: int, k: int, x_ref, out_ref):
-    x = x_ref[...]                                        # (rows, LANE) f32
-    iota = _page_iota(x.shape)
-    valid = iota < n
-    u = jax.lax.bitcast_convert_type(x, jnp.uint32)
-    # sign-magnitude bit order (ref._order_key): sign BIT, not x < 0, so
-    # +0.0 ranks strictly above -0.0 exactly like lax.top_k.
-    sign = jnp.uint32(0x80000000)
-    key = jnp.where((u & sign) != 0, ~u, u | sign)
-    key = jnp.where(valid, key, 0)                        # pads never win
+_MIN = -(1 << 31)                   # i32 sign bit
+_T, _CNT, _GE, _AFTER, _M = range(5)  # the bisection's SMEM words
 
-    def val_bit(i, t):
-        cand = t | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
-        cnt = jnp.sum((key >= cand).astype(jnp.int32))
-        return jnp.where(cnt >= k, cand, t)
 
-    t = jax.lax.fori_loop(0, 32, val_bit, jnp.uint32(0))
-    greater = key > t
-    eq = (key == t) & valid
-    need = k - jnp.sum(greater.astype(jnp.int32))         # >= 1 always
+def _topk_body(n: int, k: int, nb: int, br: int, sweeps: int, x_ref,
+               out_ref, st_ref):
+    """Grid step (lane, sweep, block).  Sweeps ``0 .. sweeps-1`` bisect the
+    k-th largest order key t, ``32 // sweeps`` bits each: a bit is decided
+    once its count covers the whole row, so a row of several blocks takes
+    one bit a sweep and a row of one block all 32 in its one sweep.  The
+    last sweep walks the blocks from the end and writes the mask: keys
+    above t, and of the keys equal to t all but the ``count(key >= t) - k``
+    of highest index (``lax.top_k``'s tie rule)."""
+    i32 = jnp.int32
+    s, j = pl.program_id(1), pl.program_id(2)
+    blk = jnp.where(s == sweeps, nb - 1 - j, j)
+    x = x_ref[...]                                        # (br, LANE) f32
+    local = _page_iota(x.shape)
+    valid = local + blk * (br * LANE) < n
+    # i32 order key: ref._order_key with the sign bit flipped, so that a
+    # signed compare ranks like ref's unsigned one (sign BIT, not x < 0:
+    # +0.0 ranks strictly above -0.0 exactly like lax.top_k); pads take
+    # the least key and never win.
+    u = jax.lax.bitcast_convert_type(x, i32)
+    key = jnp.where(valid, u ^ ((u >> 31) & ~_MIN), _MIN)
 
-    # largest m with count(eq & iota < m) < need; ties are then iota <= m.
-    # Bits 30..0 cover any n (i32 iota); bit 31 would wrap negative.
-    def idx_bit(i, m):
-        cand = m + (jnp.int32(1) << (31 - i))
-        cnt = jnp.sum((eq & (iota < cand)).astype(jnp.int32))
-        return jnp.where(cnt < need, cand, m)
+    @pl.when((s == 0) & (j == 0))
+    def _():
+        st_ref[_T] = 0                   # t's unsigned bit pattern
+        st_ref[_CNT] = 0                 # count of this bit so far
+        st_ref[_GE] = n                  # count(key >= t)
+        st_ref[_AFTER] = 0               # ties in the blocks written
 
-    m = jax.lax.fori_loop(1, 32, idx_bit, jnp.int32(0))
-    out_ref[...] = (greater | (eq & (iota <= m))).astype(jnp.int32)
+    @pl.when(s < sweeps)
+    def _():
+        last = j == nb - 1
+        bits = 32 // sweeps
+
+        def val_bit(i, carry):
+            cand = st_ref[_T] | jnp.left_shift(i32(1), 31 - (s * bits + i))
+            cnt = st_ref[_CNT] + jnp.sum((key >= (cand ^ _MIN)).astype(i32))
+            take = last & (cnt >= k)
+            st_ref[_T] = jnp.where(take, cand, st_ref[_T])
+            st_ref[_GE] = jnp.where(take, cnt, st_ref[_GE])
+            st_ref[_CNT] = jnp.where(last, 0, cnt)
+            return carry
+
+        jax.lax.fori_loop(0, bits, val_bit, 0)
+
+    @pl.when(s == sweeps)
+    def _():
+        thr = st_ref[_T] ^ _MIN
+        eq = (key == thr) & valid
+        ties = jnp.sum(eq.astype(i32))
+        # this block keeps its first ``need`` ties; the ones dropped are
+        # the row's last, counted from its end
+        drop = jnp.clip(st_ref[_GE] - k - st_ref[_AFTER], 0, ties)
+        need = ties - drop
+        st_ref[_AFTER] = st_ref[_AFTER] + ties
+        st_ref[_M] = jnp.where(drop == 0, br * LANE, -1)
+
+        idx_bits = (br * LANE - 1).bit_length()
+
+        @pl.when((need > 0) & (drop > 0))
+        def _():
+            # largest m with count(eq & local < m) < need; the ties kept
+            # are then local <= m
+            def idx_bit(i, m):
+                cand = m + jnp.left_shift(i32(1), idx_bits - 1 - i)
+                cnt = jnp.sum((eq & (local < cand)).astype(i32))
+                return jnp.where(cnt < need, cand, m)
+
+            st_ref[_M] = jax.lax.fori_loop(0, idx_bits, idx_bit, i32(0))
+
+        out_ref[...] = ((key > thr) | (eq & (local <= st_ref[_M]))
+                        ).astype(i32)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def topk_mask_kernel(x, k: int, *, interpret: bool = True):
     B, n = x.shape
-    xt = _tiles(jnp.asarray(x, jnp.float32), 0.0)
-    spec = _lane_block(xt)
+    nb, br = _record_blocks("topk_mask_kernel", n)
+    sweeps = 1 if nb == 1 else 32
+    xt = _tiles(jnp.asarray(x, jnp.float32), 0.0, nb * br)
+
+    def at(b, s, j):
+        # value sweeps read blocks in order; the mask sweep from the end.
+        # The output block stays at the last until the mask sweep writes
+        # it, so nothing unwritten goes back to HBM.
+        return b, jnp.where(s == sweeps, nb - 1 - j, j), 0
+
+    def out_at(b, s, j):
+        return b, jnp.where(s == sweeps, nb - 1 - j, nb - 1), 0
+
     out = pl.pallas_call(
-        functools.partial(_topk_body, n, k),
-        grid=(B,),
-        in_specs=[spec],
-        out_specs=spec,
+        functools.partial(_topk_body, n, k, nb, br, sweeps),
+        grid=(B, sweeps + 1, nb),
+        in_specs=[pl.BlockSpec((None, br, LANE), at)],
+        out_specs=pl.BlockSpec((None, br, LANE), out_at),
         out_shape=jax.ShapeDtypeStruct(xt.shape, jnp.int32),
+        scratch_shapes=[pltpu.SMEM((5,), jnp.int32)],
         interpret=interpret,
     )(xt)
     return _untile(out, n) != 0
@@ -278,22 +398,51 @@ def tier_migrate_kernel(tier, promote, demote, caps, *,
 
 # --------------------------------------------------- interval accounting
 def _account_body(R: int, k: int, prm_ref, true_ref, tier_ref, orc_ref,
-                  out_ref):
+                  out_ref, acc_ref, hits_ref):
     # prm row: lat[0:R] br[R:2R] bw[2R:3R] up[3R:4R-1] down[4R-1:5R-2] mlp
-    prm = [prm_ref[0, i] for i in range(5 * R - 1)]
-    lat, br, bw = prm[:R], prm[R:2 * R], prm[2 * R:3 * R]
-    up, down = prm[3 * R:4 * R - 1], prm[4 * R - 1:5 * R - 2]
-    mlp = prm[5 * R - 2]
+    j = pl.program_id(1)
 
     def total(x):                                    # -> (1, 1)
         return jnp.sum(jnp.sum(x, axis=0, keepdims=True), axis=1,
                        keepdims=True)
 
+    # this block's partial sums: accesses on tiers 0 .. R-2, all accesses
+    # (acc_ref[R-1]) and oracle hits; the first block sets the row's sums,
+    # each later one adds to them
     true = true_ref[...]                             # (rows, LANE) f32
     tier = tier_ref[...]
-    accs, rest = [], total(true)
+    parts = [total(jnp.where(tier == r, true, 0.0)) for r in range(R - 1)]
+    parts.append(total(true))
+    hits = total(((tier == 0) & (orc_ref[...] != 0)).astype(jnp.int32))
+    lane_row = (1, LANE)
+
+    @pl.when(j == 0)
+    def _():
+        for r in range(R):
+            acc_ref[r] = jnp.broadcast_to(parts[r], lane_row)
+        hits_ref[...] = jnp.broadcast_to(hits, lane_row)
+
+    @pl.when(j > 0)
+    def _():
+        for r in range(R):
+            acc_ref[r] = acc_ref[r] + parts[r]
+        hits_ref[...] = hits_ref[...] + hits
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        _account_out(R, k, prm_ref, acc_ref, hits_ref, out_ref)
+
+
+def _account_out(R: int, k: int, prm_ref, acc_ref, hits_ref, out_ref):
+    """The lane's six outputs from its whole row's sums."""
+    prm = [prm_ref[0, i] for i in range(5 * R - 1)]
+    lat, br, bw = prm[:R], prm[R:2 * R], prm[2 * R:3 * R]
+    up, down = prm[3 * R:4 * R - 1], prm[4 * R - 1:5 * R - 2]
+    mlp = prm[5 * R - 2]
+
+    accs, rest = [], acc_ref[R - 1]
     for r in range(R - 1):
-        a = total(jnp.where(tier == r, true, 0.0))
+        a = acc_ref[r]
         accs.append(a)
         rest = rest - a
     accs.append(rest)
@@ -326,8 +475,7 @@ def _account_body(R: int, k: int, prm_ref, true_ref, tier_ref, orc_ref,
         rest_acc = rest_acc + accs[r]
     slow_share = rest_acc / jnp.maximum(accs[0] + rest_acc, 1e-9)
     app_raw = times[0] / jnp.maximum(t_lat, jnp.maximum(rest_max, 1e-12))
-    hits = total(((tier == 0) & (orc_ref[...] != 0)).astype(jnp.int32))
-    recall = hits.astype(jnp.float32) / k
+    recall = hits_ref[...].astype(jnp.float32) / k
 
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANE), 1)
     row = jnp.zeros((1, LANE), jnp.float32)
@@ -347,18 +495,22 @@ def interval_account_kernel(lat, br, bw, mlp, true, tier, mig_up, mig_down,
     B, n = true.shape
     R = lat.shape[1]
     f32 = jnp.float32
+    nb, rows = _record_blocks("interval_account_kernel", n)
     prm = jnp.concatenate([lat, br, bw, mig_up, mig_down, mlp[:, None]],
                           axis=1).astype(f32)[:, None, :]
-    rows = [_tiles(true.astype(f32), 0.0), _tiles(tier, R),
-            _tiles(oracle.astype(jnp.int32), 0)]
+    pages = [_tiles(true.astype(f32), 0.0, nb * rows),
+             _tiles(tier, R, nb * rows),
+             _tiles(oracle.astype(jnp.int32), 0, nb * rows)]
     out = pl.pallas_call(
         functools.partial(_account_body, R, k),
-        grid=(B,),
-        in_specs=[_lane_smem(prm.shape[2])] + [_lane_block(x) for x in rows],
-        out_specs=pl.BlockSpec((None, 1, LANE), lambda b: (b, 0, 0)),
+        grid=(B, nb),
+        in_specs=[_lane_smem(prm.shape[2])] + [_row_block(rows)] * 3,
+        out_specs=pl.BlockSpec((None, 1, LANE), lambda b, j: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, 1, LANE), f32),
+        scratch_shapes=[pltpu.VMEM((R, 1, LANE), f32),
+                        pltpu.VMEM((1, LANE), jnp.int32)],
         interpret=interpret,
-    )(prm, *rows)
+    )(prm, *pages)
     return tuple(out[:, 0, i] for i in range(6))
 
 
@@ -381,18 +533,19 @@ def ewma_update_kernel(ewma_s, ewma_l, counts, *, alpha_s, alpha_l, w_s,
     weight param a scalar or [B] (per-lane traced values — mode-dependent
     score weights ride the lane axis)."""
     B, n = ewma_s.shape
+    nb, rows = _record_blocks("ewma_update_kernel", n)
     params = jnp.stack([jnp.broadcast_to(jnp.asarray(v, jnp.float32), (B,))
                         for v in (alpha_s, alpha_l, w_s, w_l)],
                        axis=1)[:, None, :]
-    rows = [_tiles(x, 0.0) for x in (ewma_s, ewma_l, counts)]
-    row = _lane_block(rows[0])
+    pages = [_tiles(x, 0.0, nb * rows) for x in (ewma_s, ewma_l, counts)]
+    row = _row_block(rows)
     outs = pl.pallas_call(
         _ewma_body,
-        grid=(B,),
+        grid=(B, nb),
         in_specs=[_lane_smem(4), row, row, row],
         out_specs=[row, row, row],
-        out_shape=[jax.ShapeDtypeStruct(rows[0].shape, jnp.float32)
+        out_shape=[jax.ShapeDtypeStruct(pages[0].shape, jnp.float32)
                    for _ in range(3)],
         interpret=interpret,
-    )(params, *rows)
+    )(params, *pages)
     return tuple(_untile(o, n) for o in outs)

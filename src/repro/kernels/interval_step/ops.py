@@ -10,7 +10,9 @@ Routing (resolved once per process via ``kernels/_backend``):
 
 ``tier_migrate`` keeps a lane's page row and plans in SMEM; shapes past
 that budget (``kernel.tier_migrate_fits``) take the jnp reference on
-every backend.
+every backend.  The other three kernels take any n: a row past one VMEM
+block is walked in page blocks, a layout each kernel picks from the
+shape alone (``kernel._blocks``).
 
 The references are the kernels' bitwise contract, so the scan engine's
 CRN equivalence guarantees hold on every route.  Unlike the other

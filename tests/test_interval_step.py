@@ -150,6 +150,119 @@ class TestEwmaUpdate:
             np.testing.assert_allclose(np.asarray(g), np.asarray(w), **F32)
 
 
+@pytest.fixture
+def small_blocks(monkeypatch):
+    """The blocked kernels walk rows in blocks of 8 page rows (1024
+    pages); JAX's caches are cleared so that every kernel is traced, and
+    records its blocks, under that budget."""
+    monkeypatch.setattr(kernel, "BLOCK_ROWS", 8)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+#: 3001 pages: three blocks of 1024 under ``small_blocks``, the last
+#: ending off the block and off the 128-page tile
+N_BLOCKED = 3001
+
+
+class TestBlockedRows:
+    """Rows of several page blocks: each kernel against its reference,
+    integers bitwise, f32 to the file's ulp contract."""
+
+    @pytest.mark.parametrize("k", [1, 700, 1024, 1500, 2048, 2999, 3001])
+    @pytest.mark.parametrize("levels", [1, 3, 0],
+                             ids=["all-tied", "3-levels", "distinct"])
+    def test_topk_mask(self, small_blocks, k, levels):
+        rng = np.random.default_rng(k + levels)
+        if levels:
+            # threshold-equal runs across every block boundary: the
+            # boundary tie falls at a block's edge (k = 1024, 2048) or
+            # inside one
+            x = rng.integers(0, levels, (2, N_BLOCKED)) * 0.5 - 0.5
+        else:
+            x = rng.standard_normal((2, N_BLOCKED))
+        x = jnp.asarray(x, jnp.float32)
+        with kernel.count_blocks() as blocks:
+            got = kernel.topk_mask_kernel(x, k, interpret=True)
+        assert blocks == {"topk_mask_kernel": [3]}
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(ref.topk_mask_ref(x, k)))
+
+    def test_topk_mask_signed_zero_ties(self, small_blocks):
+        # -0.0 below +0.0, and both below every positive key, across blocks
+        row = np.zeros(N_BLOCKED, np.float32)
+        row[::2] = -0.0
+        row[1500] = 1.0
+        x = jnp.asarray(np.stack([row, row[::-1]]))
+        for k in (1, 2, 1400, 1501, 3000):
+            np.testing.assert_array_equal(
+                np.asarray(kernel.topk_mask_kernel(x, k, interpret=True)),
+                np.asarray(ref.topk_mask_ref(x, k)), err_msg=str(k))
+
+    @pytest.mark.parametrize("B,n", [(3, N_BLOCKED), (2, 2048)])
+    def test_interval_account(self, small_blocks, B, n):
+        k = n // 4
+        rng = np.random.default_rng(n + B)
+        mach, caps = _tiered(rng, B, n, k)
+        R = caps.shape[-1]
+        true = jnp.asarray(rng.gamma(1.5, 2.0, (B, n)), jnp.float32)
+        tier = jnp.asarray(rng.integers(0, R, (B, n)), jnp.int32)
+        up = jnp.asarray(rng.integers(0, 5, (B, R - 1)), jnp.float32)
+        down = jnp.asarray(rng.integers(0, 5, (B, R - 1)), jnp.float32)
+        oracle = ref.topk_mask_ref(true, k)
+        want = ref.interval_account_ref(mach, true, tier, up, down,
+                                        oracle, k)
+        with kernel.count_blocks() as blocks:
+            got = kernel.interval_account_kernel(
+                mach.lat_ns, mach.bw_read, mach.bw_write, mach.mlp, true,
+                tier, up, down, oracle, k, interpret=True)
+        assert blocks == {"interval_account_kernel": [-(-n // 1024)]}
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w), **F32)
+
+    @pytest.mark.parametrize("lane_params", [False, True])
+    def test_ewma_update(self, small_blocks, lane_params):
+        B, n = 2, N_BLOCKED
+        rng = np.random.default_rng(n + lane_params)
+        s, l = (jnp.asarray(rng.random((B, n)), jnp.float32)
+                for _ in range(2))
+        c = jnp.asarray(rng.poisson(5, (B, n)), jnp.float32)
+        if lane_params:
+            kw = {nm: jnp.asarray(rng.random(B), jnp.float32)
+                  for nm in ("alpha_s", "alpha_l", "w_s", "w_l")}
+        else:
+            kw = dict(alpha_s=0.7, alpha_l=0.1, w_s=0.2, w_l=0.8)
+        want = ref.ewma_score_update_ref(s, l, c, **kw)
+        with kernel.count_blocks() as blocks:
+            got = kernel.ewma_update_kernel(s, l, c, interpret=True, **kw)
+        assert blocks == {"ewma_update_kernel": [3]}
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w), **F32)
+
+    @pytest.mark.parametrize("n,blocks", [(65536, 1), (131072, 1),
+                                          (131073, 2), (2097152, 16)])
+    def test_block_count_from_shape(self, n, blocks):
+        """The benchmark's 65,536-page rows keep one block; 2**21 pages
+        take 16 (traced only, nothing runs)."""
+        jax.clear_caches()
+        f32, B = jnp.float32, 2
+        row = jax.ShapeDtypeStruct((B, n), f32)
+        lane = jax.ShapeDtypeStruct((B,), f32)
+        with kernel.count_blocks() as got:
+            jax.eval_shape(lambda x: kernel.topk_mask_kernel(x, n // 4),
+                           row)
+            jax.eval_shape(
+                lambda s, l, c, a: kernel.ewma_update_kernel(
+                    s, l, c, alpha_s=a, alpha_l=0.1, w_s=a, w_l=0.5),
+                row, row, row, lane)
+        assert got == {"topk_mask_kernel": [blocks],
+                       "ewma_update_kernel": [blocks]}
+        nb, rows = kernel._blocks(n)
+        assert nb * rows * kernel.LANE >= n and rows <= kernel.BLOCK_ROWS
+        assert nb == 1 or rows % 8 == 0
+
+
 class TestMigrateKernelOddShapes:
     """Existing kernels/migrate data-plane kernel: odd page/feat tiles
     (not multiples of the f32 8x128 TPU tile) and empty batches."""

@@ -7,7 +7,9 @@ memory spaces and scalar accesses.  Each test lowers a kernel with
 and compiles it, at the sweep's real width (B = 64 lanes, n = 65536
 pages, 3 tiers) and at one odd shape (non-multiple-of-8 lanes, a page
 count off the 128-lane tile, 2 tiers, an empty migration plan); the
-migration kernel also at the edge of its SMEM budget.
+migration kernel also at the edge of its SMEM budget, and the three
+blocked kernels at 2**21 pages (4 TiB of 2 MiB pages), where a row
+takes 16 page blocks.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this
@@ -27,6 +29,8 @@ ODD = dict(B=5, n=1000, R=2)
 PLAN = 16384          # plan width of a k = 16384 policy at n = 65536
 #: tier row + both plans fill ``kernel.SMEM_WORDS`` exactly
 EDGE = dict(B=8, n=65536, R=3)
+#: 18 lanes of 2,097,152 pages: rows far past one VMEM block
+HUGE = dict(B=18, n=2097152, R=2)
 
 
 @pytest.fixture(scope="module")
@@ -65,7 +69,8 @@ def _compile(fn, sharding, *shapes, **static):
 f32, i32 = jnp.float32, jnp.int32
 
 
-@pytest.mark.parametrize("shape", [WIDE, ODD], ids=["wide", "odd"])
+@pytest.mark.parametrize("shape", [WIDE, ODD, HUGE],
+                         ids=["wide", "odd", "huge"])
 def test_topk_mask_compiles(one_chip, shape):
     B, n = shape["B"], shape["n"]
     _compile(kernel.topk_mask_kernel, one_chip, ((B, n), f32), k=n // 4)
@@ -81,7 +86,8 @@ def test_tier_migrate_compiles(one_chip, shape, P, D):
              ((B, P), i32), ((B, D), i32), ((B, R), i32))
 
 
-@pytest.mark.parametrize("shape", [WIDE, ODD], ids=["wide", "odd"])
+@pytest.mark.parametrize("shape", [WIDE, ODD, HUGE],
+                         ids=["wide", "odd", "huge"])
 def test_interval_account_compiles(one_chip, shape):
     B, n, R = shape["B"], shape["n"], shape["R"]
     _compile(kernel.interval_account_kernel, one_chip,
@@ -90,7 +96,8 @@ def test_interval_account_compiles(one_chip, shape):
              ((B, R - 1), f32), ((B, n), jnp.bool_), k=n // 4)
 
 
-@pytest.mark.parametrize("shape", [WIDE, ODD], ids=["wide", "odd"])
+@pytest.mark.parametrize("shape", [WIDE, ODD, HUGE],
+                         ids=["wide", "odd", "huge"])
 def test_ewma_update_compiles(one_chip, shape):
     B, n = shape["B"], shape["n"]
 
