@@ -218,7 +218,8 @@ def sweep(policies, *, workloads=None, trace=None, machines="pmem-large",
     ``experiment.build`` (once for the shared specs, then per group for
     its lane layout and keys), and per group ``experiment.dispatch``
     (the enqueue), ``experiment.wait`` (until the results exist) and
-    ``experiment.readback`` (``_to_result`` over the group's lanes).
+    ``experiment.readback`` (``scan_engine._to_results``: the group's
+    whole output in one host transfer, then its lanes sliced on the host).
     """
     reduce = "stack" if timelines else "stream"
     with TraceAnnotation("experiment.build"):
@@ -334,7 +335,6 @@ def sweep(policies, *, workloads=None, trace=None, machines="pmem-large",
                     sampling, scan_engine._need_normal(trace, min_period),
                     interval_kernel=use_interval_kernel, reduce=reduce,
                     mesh=mesh, pad_multiple=_pad_multiple)
-            out = scan_engine._timelines_lane_major(out)
         scan_engine._record_dispatch(
             lanes=L, sampling=sampling, policy=lane_specs[idxs[0]].name,
             synth=synth, workloads=W, configs=Pg, machines=M, seeds=S, T=T,
@@ -344,6 +344,7 @@ def sweep(policies, *, workloads=None, trace=None, machines="pmem-large",
         with TraceAnnotation("experiment.wait"):
             jax.block_until_ready(out)
         with TraceAnnotation("experiment.readback"):
+            names, slots = [], []
             for l in range(L):
                 w = l // (Pg * M * S)
                 p = idxs[p_local[l]]
@@ -351,8 +352,10 @@ def sweep(policies, *, workloads=None, trace=None, machines="pmem-large",
                 name = f"{pol_specs[p].name}@{wl_names[w]}[{mach_labels[m]}]"
                 if S > 1:
                     name += f"[seed={seeds[s]}]"
-                grid[((p * W + w) * M + m) * S + s] = scan_engine._to_result(
-                    out, l, name)
+                names.append(name)
+                slots.append(((p * W + w) * M + m) * S + s)
+            for slot, res in zip(slots, scan_engine._to_results(out, names)):
+                grid[slot] = res
 
     axes = dict(policy=_dedup_labels([sp.name for sp in pol_specs]),
                 workload=_dedup_labels(wl_names),

@@ -374,7 +374,8 @@ def _pad_lanes(tree, B: int, Lp: int):
 
 def _unpad_out(out: dict, B: int) -> dict:
     """Drop padded lanes from a raw engine output dict ([B]-leading
-    scalars; ``timeline_*`` are [T, B] until _timelines_lane_major)."""
+    scalars; ``timeline_*`` are [T, B], made lane-major on the host by
+    ``scan_engine._to_results``)."""
     return {key: (v[:, :B] if key.startswith("timeline_") else v[:B])
             for key, v in out.items()}
 

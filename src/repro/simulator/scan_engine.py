@@ -55,8 +55,8 @@ policy (see tests/test_scan_engine.py).
 NOTE on the module boundary: ``simulator/experiment.py`` (the axis-product
 orchestrator) assembles lanes directly on this module's underscore helpers
 (``_sim_jit``/``_sim_synth_jit``, ``_stack_specs``/``_stack_workloads``/
-``_take_lanes``, ``_need_normal``/``_synth_need_normal``, ``_to_result``/
-``_timelines_lane_major``/``_record_dispatch``).  They are a load-bearing
+``_take_lanes``, ``_need_normal``/``_synth_need_normal``, ``_to_results``/
+``_record_dispatch``).  They are a load-bearing
 internal contract shared by exactly those two modules — change their
 signatures in lockstep.
 """
@@ -83,7 +83,7 @@ __all__ = [
     "SWEEPABLE", "simulate", "sweep_seeds", "sweep_policy_configs",
     "arms_sim", "sweep_arms_configs", "simulate_workload",
     "sweep_workloads", "sweep_workload_configs", "last_dispatch",
-    "count_dispatches", "DispatchCounter",
+    "count_dispatches", "count_readbacks", "DispatchCounter",
 ]
 
 #: Info about the most recent compiled dispatch (lanes, sampling mode).
@@ -93,8 +93,8 @@ last_dispatch: dict = {}
 
 
 class DispatchCounter:
-    """Live tally handed out by ``count_dispatches``: ``count`` dispatches
-    so far, ``records`` their ``_record_dispatch`` info dicts in order."""
+    """Live tally handed out by ``count_dispatches`` / ``count_readbacks``:
+    ``count`` events so far, ``records`` their info dicts in order."""
 
     def __init__(self):
         self.count = 0
@@ -105,12 +105,23 @@ class DispatchCounter:
         return self.records[-1] if self.records else {}
 
 
-#: counters currently open via ``count_dispatches`` (nesting is fine: every
-#: open counter sees every dispatch issued inside its region).
+#: counters currently open via ``count_dispatches`` / ``count_readbacks``
+#: (nesting is fine: every open counter sees every event issued inside its
+#: region).
 _active_counters: list = []
+_active_readbacks: list = []
 
 
 @contextlib.contextmanager
+def _counting(active: list):
+    ctr = DispatchCounter()
+    active.append(ctr)
+    try:
+        yield ctr
+    finally:
+        active.remove(ctr)
+
+
 def count_dispatches():
     """Context-managed dispatch counter for gates and the search engine.
 
@@ -121,12 +132,16 @@ def count_dispatches():
     Concurrent/nested measured regions cannot race: each region owns its
     counter and only dispatches issued within the region are tallied.
     """
-    ctr = DispatchCounter()
-    _active_counters.append(ctr)
-    try:
-        yield ctr
-    finally:
-        _active_counters.remove(ctr)
+    return _counting(_active_counters)
+
+
+def count_readbacks():
+    """Context-managed counter of device->host result transfers (one
+    ``jax.device_get`` of a dispatch's whole output tree, ``_to_results``),
+    with ``count_dispatches``'s contract; ``records`` hold ``{"lanes": L}``.
+    A sweep reads back once per dispatch group, whatever its lane count.
+    """
+    return _counting(_active_readbacks)
 
 
 def _need_normal(trace, min_period: float) -> bool:
@@ -686,38 +701,50 @@ def _synth_need_normal(wl_specs, min_period: float) -> bool:
         >= _NORMAL_SWITCH
 
 
-def _to_result(out, lane: int, name: str) -> SimResult:
-    lane_out = jax.tree_util.tree_map(lambda x: x[lane], out)
+def _to_result(host, lane: int, name: str) -> SimResult:
+    """Lane ``lane`` of a host copy of the engine's output (numpy leaves,
+    ``timeline_*`` lane-major) -> ``SimResult``: the scalars as Python
+    ``float``/``int``, the timelines as float64 / int32 arrays."""
     res = SimResult(
         name=name,
-        exec_time_s=float(lane_out["exec_time"]),
-        promotions=int(lane_out["promotions"]),
-        demotions=int(lane_out["demotions"]),
-        wasteful=int(lane_out["wasteful"]),
-        hot_recall=float(lane_out["hot_recall"]),
-        fast_hit_frac=float(lane_out["fast_hit_frac"]))
-    if "timeline_slow_bw" in lane_out:       # reduce="stack"
-        ts = {k: np.asarray(v) for k, v in lane_out.items()
-              if k.startswith("timeline_")}
-        res.timeline_slow_bw = ts["timeline_slow_bw"].astype(np.float64)
-        res.timeline_fast_hits = ts["timeline_fast_hits"].astype(np.float64)
-        res.timeline_mode = ts["timeline_mode"].astype(np.int32)
-        res.timeline_promotions = ts["timeline_promotions"].astype(np.int32)
+        exec_time_s=float(host["exec_time"][lane]),
+        promotions=int(host["promotions"][lane]),
+        demotions=int(host["demotions"][lane]),
+        wasteful=int(host["wasteful"][lane]),
+        hot_recall=float(host["hot_recall"][lane]),
+        fast_hit_frac=float(host["fast_hit_frac"][lane]))
+    if "timeline_slow_bw" in host:           # reduce="stack"
+        res.timeline_slow_bw = host["timeline_slow_bw"][lane].astype(
+            np.float64)
+        res.timeline_fast_hits = host["timeline_fast_hits"][lane].astype(
+            np.float64)
+        res.timeline_mode = host["timeline_mode"][lane].astype(np.int32)
+        res.timeline_promotions = host["timeline_promotions"][lane].astype(
+            np.int32)
     else:                                    # reduce="stream" summaries
-        res.mean_slow_bw = float(lane_out["mean_slow_bw"])
-        res.mean_fast_hits = float(lane_out["mean_fast_hits"])
-        res.mean_mode = float(lane_out["mean_mode"])
+        res.mean_slow_bw = float(host["mean_slow_bw"][lane])
+        res.mean_fast_hits = float(host["mean_fast_hits"][lane])
+        res.mean_mode = float(host["mean_mode"][lane])
         res.max_promotions_interval = int(
-            lane_out["max_promotions_interval"])
+            host["max_promotions_interval"][lane])
     return res
 
 
-def _timelines_lane_major(out):
-    """scan stacks timelines as [T, B]; give callers [B, T]."""
-    for key in list(out):
-        if key.startswith("timeline_"):
-            out[key] = jnp.swapaxes(out[key], 0, 1)
-    return out
+def _to_results(out, names) -> list[SimResult]:
+    """A dispatch's raw output (``[B]`` leaves; ``timeline_*`` ``[T, B]``)
+    -> one ``SimResult`` per lane, lane ``i`` named ``names[i]``.
+
+    ONE ``jax.device_get`` brings the whole tree to the host (every
+    leaf's copy starts before any is waited on); the lanes are then numpy
+    slices, so no device op and no blocking read is issued per lane or
+    per field."""
+    host = jax.device_get(out)
+    for ctr in _active_readbacks:
+        ctr.count += 1
+        ctr.records.append(dict(lanes=len(names)))
+    host = {key: np.swapaxes(v, 0, 1) if key.startswith("timeline_") else v
+            for key, v in host.items()}
+    return [_to_result(host, i, nm) for i, nm in enumerate(names)]
 
 
 def _record_dispatch(**info):
@@ -770,7 +797,7 @@ def simulate(spec, trace, machine, k: int, seed: int = 0, sample_u=None,
     _record_dispatch(lanes=1, sampling="crn" if crn else "prng",
                      policy=spec.name, machines=1, T=trace.shape[0],
                      interval_kernel=use_interval_kernel, reduce="stack")
-    return _to_result(_timelines_lane_major(out), 0, name or spec.name)
+    return _to_results(out, [name or spec.name])[0]
 
 
 def sweep_seeds(trace, machine, k: int, seeds, cfg: ARMSConfig | None = None,
@@ -801,9 +828,7 @@ def sweep_seeds(trace, machine, k: int, seeds, cfg: ARMSConfig | None = None,
     _record_dispatch(lanes=len(seeds), sampling="prng", policy=spec.name,
                      machines=1, T=trace.shape[0], interval_kernel=True,
                      reduce="stack")
-    out = _timelines_lane_major(out)
-    return [_to_result(out, i, f"{spec.name}[seed={s}]")
-            for i, s in enumerate(seeds)]
+    return _to_results(out, [f"{spec.name}[seed={s}]" for s in seeds])
 
 
 def sweep_policy_configs(spec_family, trace, machine, k: int, configs,
@@ -841,11 +866,9 @@ def sweep_policy_configs(spec_family, trace, machine, k: int, configs,
     _record_dispatch(lanes=len(configs), sampling="crn",
                      policy=specs[0].name, machines=1, T=T,
                      interval_kernel=True, reduce="stack")
-    out = _timelines_lane_major(out)
     labels = [",".join(f"{nm}={v:.6g}" for nm, v in sorted(cfg.items()))
               for cfg in configs]
-    return [_to_result(out, i, f"{specs[0].name}[{lbl}]")
-            for i, lbl in enumerate(labels)]
+    return _to_results(out, [f"{specs[0].name}[{lbl}]" for lbl in labels])
 
 
 def arms_sim(trace, machine, k: int, cfg: ARMSConfig | None = None,
@@ -896,11 +919,9 @@ def sweep_arms_configs(trace, machine, k: int, overrides: dict,
                        ARMSSpec.PRE_PERIODS, need_normal, reduce=reduce)
     _record_dispatch(lanes=B, sampling="pre", policy="arms", machines=1,
                      T=T, interval_kernel=True, reduce=reduce)
-    out = _timelines_lane_major(out)
     labels = [",".join(f"{nm}={float(overrides[nm][b]):.4g}" for nm in names)
               for b in range(B)]
-    return [_to_result(out, i, f"arms[{lbl}]")
-            for i, lbl in enumerate(labels)]
+    return _to_results(out, [f"arms[{lbl}]" for lbl in labels])
 
 
 # --------------------------------------------- trace synthesis (workloads)
@@ -939,7 +960,7 @@ def simulate_workload(spec, workload, machine, k: int, T: int, n: int,
                      machines=1, T=T, interval_kernel=use_interval_kernel,
                      reduce="stack")
     label = name or f"{spec.name}@{workload_spec.label_of(workload)}"
-    return _to_result(_timelines_lane_major(out), 0, label)
+    return _to_results(out, [label])[0]
 
 
 def sweep_workloads(workloads, machine, k: int, T: int, n: int,
@@ -976,9 +997,7 @@ def sweep_workloads(workloads, machine, k: int, T: int, n: int,
     _record_dispatch(lanes=W, sampling="crn_prng", policy=spec.name,
                      synth=True, workloads=W, configs=1, machines=1, T=T,
                      interval_kernel=True, reduce="stack")
-    out = _timelines_lane_major(out)
-    return [_to_result(out, i, f"{spec.name}@{nm}")
-            for i, nm in enumerate(names)]
+    return _to_results(out, [f"{spec.name}@{nm}" for nm in names])
 
 
 def sweep_workload_configs(spec_family, configs, workloads, machine, k: int,
@@ -1026,9 +1045,8 @@ def sweep_workload_configs(spec_family, configs, workloads, machine, k: int,
                      policy=pol_specs[0].name, synth=True, workloads=W,
                      configs=B, machines=1, T=T, interval_kernel=True,
                      reduce="stack")
-    out = _timelines_lane_major(out)
     labels = [",".join(f"{nm}={v:.6g}" for nm, v in sorted(cfg.items()))
               for cfg in configs]
-    return [[_to_result(out, w * B + b,
-                        f"{pol_specs[b].name}@{names[w]}[{labels[b]}]")
-             for b in range(B)] for w in range(W)]
+    res = _to_results(out, [f"{pol_specs[b].name}@{names[w]}[{labels[b]}]"
+                            for w in range(W) for b in range(B)])
+    return [res[w * B:(w + 1) * B] for w in range(W)]
