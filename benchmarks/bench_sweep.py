@@ -16,7 +16,7 @@ comparators), the "before" is the pre-functional-protocol path: HeMem as an
 imperative numpy object (``SeqNumpyHeMemPolicy`` replica below) replaying
 the whole budget sequentially through the reference engine.  The "after"
 runs the same budget as ONE lane-batched compiled scan
-(``tuning.tune_hemem`` -> ``scan_engine.sweep_policy_configs``).
+(``tuning.tune_hemem`` -> ``search.run`` -> ``experiment.sweep``).
 
 Usage: PYTHONPATH=src:. python benchmarks/bench_sweep.py [--out BENCH_tuning.json]
 """

@@ -24,7 +24,7 @@ import time
 
 from benchmarks import common
 from repro.baselines.hemem import HeMemSpec
-from repro.simulator import scan_engine, workload_spec, workloads
+from repro.simulator import experiment, scan_engine, workload_spec, workloads
 from repro.simulator.engine import oracle_topk_masks
 from repro.simulator.machine import PMEM_LARGE
 from repro.simulator.sampling import uniform_field
@@ -50,6 +50,7 @@ def main():
     wl_names = args.workloads.split(",")
     W, B = len(wl_names), args.budget
     cfgs = [dict(hot_threshold=float(h)) for h in (4, 8, 16, 32)][:B]
+    pol_specs = [HeMemSpec.make(**c) for c in cfgs]
     specs = [workloads.spec(nm, T=T) for nm in wl_names]
 
     rec = dict(n_pages=n, T=T, k=k, workloads=wl_names, budget=B,
@@ -61,13 +62,11 @@ def main():
     print(f"[bench_workloads] synth sweep: {W} workloads x {B} configs, "
           f"n={n} T={T} k={k}", flush=True)
     mat_before = workload_spec.MATERIALIZE_CALLS
-    _, cold_s = common.timed(
-        scan_engine.sweep_workload_configs, HeMemSpec.make, cfgs, specs,
-        PMEM_LARGE, k, T, n, names=wl_names)
+    kw = dict(workloads=specs, machines=PMEM_LARGE, k=k, T=T, n=n,
+              timelines=True)
+    _, cold_s = common.timed(experiment.sweep, pol_specs, **kw)
     rec["synth_sweep_cold_s"] = round(cold_s, 3)
-    _, warm_s = common.timed(
-        scan_engine.sweep_workload_configs, HeMemSpec.make, cfgs, specs,
-        PMEM_LARGE, k, T, n, names=wl_names)
+    _, warm_s = common.timed(experiment.sweep, pol_specs, **kw)
     rec["synth_sweep_warm_s"] = round(warm_s, 3)
     rec["synth_lanes"] = scan_engine.last_dispatch["lanes"]
     rec["synth_materialize_calls"] = \
@@ -85,12 +84,12 @@ def main():
         trace = sp.materialize(T, n)
         mat_s += time.time() - t0
         t0 = time.time()
-        oracle_topk_masks(trace, k)     # what trace-mode simulate() pays
+        oracle_topk_masks(trace, k)     # what a trace-mode sweep pays
         orc_s += time.time() - t0
         t0 = time.time()
-        scan_engine.sweep_policy_configs(
-            HeMemSpec.make, trace, PMEM_LARGE, k, cfgs,
-            sample_u=uniform_field(T, n, seed=0))
+        experiment.sweep(pol_specs, trace=trace, machines=PMEM_LARGE, k=k,
+                         sample_u=uniform_field(T, n, seed=0),
+                         timelines=True)
         sweep_s += time.time() - t0
         print(f"[bench_workloads] materialized {nm}: done "
               f"(cum mat {mat_s:.1f}s orc {orc_s:.1f}s sweep {sweep_s:.1f}s)",

@@ -179,19 +179,21 @@ def bench_machine_sweep_gate():
     res, cold = common.timed(
         experiment.sweep, specs, workloads=[wl], machines=mach_names,
         k=k, T=T_, n=n, sim_seed=sim_seed)
-    _, warm = common.timed(
-        experiment.sweep, specs, workloads=[wl], machines=mach_names,
-        k=k, T=T_, n=n, sim_seed=sim_seed)
+    with scan_engine.count_dispatches() as ctr:
+        _, warm = common.timed(
+            experiment.sweep, specs, workloads=[wl], machines=mach_names,
+            k=k, T=T_, n=n, sim_seed=sim_seed)
 
-    d = dict(scan_engine.last_dispatch)
+    d = ctr.last
     claim("machine sweep runs as ONE P*M-lane dispatch",
           f"lanes={d.get('lanes')} for {P} configs x {M} machines "
           f"(mixed 2/3-tier)",
           "P*M lanes, no per-machine recompiles or sequential fallback",
           d.get("lanes") == P * M and d.get("machines") == M
           and d.get("axis_product") is True)
-    single = scan_engine.simulate_workload(specs[0], wl, "dram-cxl-pmem",
-                                           k, T_, n, sim_seed=sim_seed)
+    single = experiment.sweep([specs[0]], workloads=[wl],
+                              machines="dram-cxl-pmem", k=k, T=T_, n=n,
+                              sim_seed=sim_seed).grid[0]
     lane = res.at(policy=0, machine="dram-cxl-pmem")
     claim("machine-sweep lane == standalone single-machine run",
           f"P/D/W {lane.promotions}/{lane.demotions}/{lane.wasteful}",
@@ -759,9 +761,9 @@ def bench_arms_sweep(budget: int = 24, n_seeds: int = 8,
     """
     import time
 
-    from repro.baselines.arms_policy import ARMSPolicy
+    from repro.baselines.arms_policy import ARMSPolicy, ARMSSpec
     from repro.core.state import ARMSConfig
-    from repro.simulator import scan_engine, workloads
+    from repro.simulator import experiment, workloads
 
     trace = workloads.make("gups", T=T, n=n)
     k = n // 8
@@ -775,22 +777,22 @@ def bench_arms_sweep(budget: int = 24, n_seeds: int = 8,
         run(ARMSPolicy(ARMSConfig(**cfg)), trace, PMEM_LARGE, k, seed=0)
     rec["config_sweep_sequential_s"] = round(time.time() - t0, 3)
 
-    overrides = {key: [c[key] for c in cfgs] for key in tuning.ARMS_SPACE}
+    kw = dict(trace=trace, machines=PMEM_LARGE, k=k, timelines=True)
+    specs = [ARMSSpec.make(cfg) for cfg in cfgs]
     t0 = time.time()
-    scan_engine.sweep_arms_configs(trace, PMEM_LARGE, k, overrides)
+    experiment.sweep(specs, **kw)
     rec["config_sweep_batched_cold_s"] = round(time.time() - t0, 3)
     t0 = time.time()
-    scan_engine.sweep_arms_configs(trace, PMEM_LARGE, k, overrides)
+    experiment.sweep(specs, **kw)
     rec["config_sweep_batched_warm_s"] = round(time.time() - t0, 3)
 
     # same sweep with the pure-jnp score path (the Pallas kernel runs in
     # interpret mode off-TPU, which costs extra under batching)
     jnp_cfg = ARMSConfig(use_score_kernel=False)
-    scan_engine.sweep_arms_configs(trace, PMEM_LARGE, k, overrides,
-                                   base_cfg=jnp_cfg)
+    jnp_specs = [ARMSSpec.make(cfg, base_cfg=jnp_cfg) for cfg in cfgs]
+    experiment.sweep(jnp_specs, **kw)
     t0 = time.time()
-    scan_engine.sweep_arms_configs(trace, PMEM_LARGE, k, overrides,
-                                   base_cfg=jnp_cfg)
+    experiment.sweep(jnp_specs, **kw)
     rec["config_sweep_batched_warm_jnp_s"] = round(time.time() - t0, 3)
 
     # --- seed sweep ---
@@ -800,10 +802,10 @@ def bench_arms_sweep(budget: int = 24, n_seeds: int = 8,
         run(ARMSPolicy(), trace, PMEM_LARGE, k, seed=s)
     rec["seed_sweep_sequential_s"] = round(time.time() - t0, 3)
     t0 = time.time()
-    scan_engine.sweep_seeds(trace, PMEM_LARGE, k, seeds)
+    experiment.sweep(["arms"], seeds=seeds, **kw)
     rec["seed_sweep_batched_cold_s"] = round(time.time() - t0, 3)
     t0 = time.time()
-    scan_engine.sweep_seeds(trace, PMEM_LARGE, k, seeds)
+    experiment.sweep(["arms"], seeds=seeds, **kw)
     rec["seed_sweep_batched_warm_s"] = round(time.time() - t0, 3)
 
     sp_cfg = rec["config_sweep_sequential_s"] / \

@@ -22,8 +22,8 @@ import numpy as np
 from repro.baselines.base import Policy
 from repro.baselines.protocol import PolicySpec
 from repro.core import ARMSConfig, arms_step, init_state
-from repro.core.controller import (MODE_SAMPLING_PERIODS,
-                                   POLICY_EVERY_HISTORY, POLICY_EVERY_RECENCY,
+from repro.core.controller import (POLICY_EVERY_HISTORY,
+                                   POLICY_EVERY_RECENCY,
                                    SAMPLING_PERIOD_HISTORY,
                                    SAMPLING_PERIOD_RECENCY, arms_step_impl,
                                    policy_every, sampling_period)
@@ -65,8 +65,6 @@ class ARMSSpec(PolicySpec):
     name = "arms"
     dynamic_sampling_period = True
     has_mode = True
-    #: mode-indexed sampling periods for precomputed CRN observation grids
-    PRE_PERIODS = MODE_SAMPLING_PERIODS
 
     @classmethod
     def make(cls, overrides: dict | None = None,
@@ -124,10 +122,6 @@ class ARMSSpec(PolicySpec):
 
     def mode_of(self, state):
         return state.inner.mode
-
-    def obs_index(self, state):
-        """Index into the PRE_PERIODS observation grids ("pre" sampling)."""
-        return (state.inner.mode == MODE_RECENCY).astype(jnp.int32)
 
     def policy(self, state, slow_bw, app_bw, k):
         cfg = self.cfg()

@@ -24,9 +24,11 @@ Padded-index contract
 the sentinel ``-1`` are padding and are skipped; the remaining entries are
 page indices in priority order (hottest/most-urgent first).  The engines
 execute demotions first, then promotions capped by free capacity — see
-``simjax.apply_padded_migrations`` (scan engine) and the variable-length
-equivalent in ``engine.run`` (numpy engine); both agree exactly (property-
-tested in tests/test_policy_protocol.py).
+``simjax.apply_padded_migrations`` (the two-tier form, which the serving
+pools run) and the variable-length equivalent in ``engine.run`` (numpy
+engine); both agree exactly (property-tested in
+tests/test_policy_protocol.py).  The scan engine runs the N-tier form,
+``simjax.apply_tier_migrations``.
 
 Tier-native contract
 --------------------
@@ -52,8 +54,10 @@ identical plans and a policy's residency belief stays exact.
 Binary specs need no changes: the base ``tier_policy`` is a shim that
 concatenates ``policy``'s demotions (dst=DST_BELOW) and promotions
 (dst=0), which ``apply_targeted_migrations`` executes bitwise-identically
-to the hop-chain path — asserted for all six families in
-tests/test_tier_native.py.
+to the hop-chain path.  A union sweep (``experiment.sweep(...,
+dispatch="union")``, simulator/fabric.py) runs every binary member this
+way; tests/test_tier_native.py compares it with the grouped dispatch for
+all six binary families.
 
 ``LegacyPolicyAdapter`` wraps a spec back into the stateful ``Policy``
 interface so the numpy reference engine keeps replaying every policy with

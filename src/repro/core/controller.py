@@ -33,9 +33,6 @@ __all__ = [
 # §5: PEBS sampling period 10k default, 5k in recency mode.
 SAMPLING_PERIOD_HISTORY = 10_000
 SAMPLING_PERIOD_RECENCY = 5_000
-# Mode-indexed sampling periods (index = MODE_HISTORY / MODE_RECENCY); the
-# scan engine precomputes one CRN observation grid per entry.
-MODE_SAMPLING_PERIODS = (SAMPLING_PERIOD_HISTORY, SAMPLING_PERIOD_RECENCY)
 # §5: policy thread every 500ms steady, 100ms after a hot-set change.
 POLICY_EVERY_HISTORY = 5
 POLICY_EVERY_RECENCY = 1

@@ -1,8 +1,9 @@
 """Pure-jnp oracles for the fused per-interval fast path.
 
 Each reference is the BITWISE contract for its Pallas kernel *and* for the
-unfused scan-engine path it replaces (scan_engine._simulate with
-``use_interval_kernel=False``):
+unfused scan-engine path it replaces (``scan_engine._simulate`` with
+``interval_kernel=False``, i.e. ``experiment.sweep(...,
+use_interval_kernel=False)``):
 
   * ``topk_mask_ref`` computes the exact top-k mask by threshold bisection
     over the order-preserving uint32 transform of f32 — no ``lax.top_k``

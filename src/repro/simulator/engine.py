@@ -92,8 +92,8 @@ def oracle_topk_masks(trace: np.ndarray, k: int) -> np.ndarray:
     ``jax.lax.top_k`` exactly — strictly-greater values first, then
     threshold-equal values by ascending page index — so the
     device-computed oracle of the trace-synthesis path
-    (``scan_engine.simulate_workload``) agrees bitwise with this host mask
-    on the same f32 trace.
+    (``experiment.sweep(workloads=...)``) agrees bitwise with this host
+    mask on the same f32 trace.
     """
     trace = np.asarray(trace)
     n = trace.shape[1]

@@ -48,16 +48,19 @@ removes:
   union state memory is the max family's, not the sum) — and whose
   per-lane ``fam`` index selects the active member via ``lax.switch``.
   Every lane runs the tier-targeted route; binary members go through
-  the protocol's base shim, which PR 8 proved bitwise-equal to the
-  hop-chain path under CRN.  Mixed observation kinds (oracle lanes see
-  true counts; TPP lanes carry a per-slow-access overhead) ride
-  per-lane leaves consulted by the engine's ``mixed_observation``
-  hooks.  A full mixed-family robustness board therefore compiles to
-  literally ONE program, bitwise-equal to the per-family grouped path.
+  the protocol's base shim, bitwise-equal to the hop-chain path under
+  CRN (tests/test_tier_native.py compares ``dispatch="union"`` with
+  ``dispatch="grouped"`` for each binary family).  Mixed observation
+  kinds (oracle lanes see true counts; TPP lanes carry a
+  per-slow-access overhead) ride per-lane leaves consulted by the
+  engine's ``mixed_observation`` hooks.  A full mixed-family
+  robustness board therefore compiles to literally ONE program,
+  bitwise-equal to the per-family grouped path.
 
-``experiment.sweep(dispatch=..., mesh=...)`` is the public face; the
-entry points here share the scan engine's underscore-helper contract
-(change signatures in lockstep).
+``experiment.sweep(dispatch=..., mesh=...)`` is the public face and the
+only caller of ``sim_trace`` / ``sim_synth``, which hand the scan
+engine's jits (``scan_engine._sim_jit`` / ``_sim_synth_jit``) their
+arguments unchanged on the plain path.
 """
 from __future__ import annotations
 
@@ -396,18 +399,15 @@ def _out_specs(reduce: str) -> dict:
 
 @functools.partial(
     jax.jit, static_argnames=("k", "sampling", "need_normal",
-                              "interval_kernel", "reduce", "tier_shim",
-                              "mesh"),
+                              "interval_kernel", "reduce", "mesh"),
     donate_argnums=(0, 4, 5, 6))
 def _fab_trace_jit(spec, trace, oracle_mask, k, mach, caps, keys, sample,
-                   sampling, need_normal, interval_kernel, reduce,
-                   tier_shim, mesh):
+                   sampling, need_normal, interval_kernel, reduce, mesh):
     lane, rep = P(LANE_AXIS), P()
     f = jax.shard_map(
         lambda sp, tr, om, mc, cp, ky, sm: scan_engine._simulate(
             sp, tr, om, k, mc, cp, ky, sm, sampling, need_normal,
-            interval_kernel=interval_kernel, reduce=reduce,
-            tier_shim=tier_shim),
+            interval_kernel=interval_kernel, reduce=reduce),
         mesh=mesh,
         in_specs=(lane, rep, rep, lane, lane, lane, rep),
         out_specs=_out_specs(reduce), check_vma=False)
@@ -417,11 +417,11 @@ def _fab_trace_jit(spec, trace, oracle_mask, k, mach, caps, keys, sample,
 @functools.partial(
     jax.jit, static_argnames=("k", "sampling", "need_normal", "n",
                               "wl_boost", "wl_drift", "interval_kernel",
-                              "reduce", "tier_shim", "mesh"),
+                              "reduce", "mesh"),
     donate_argnums=(0, 3, 4, 5, 9))
 def _fab_synth_jit(spec, wl, k, mach, caps, keys, sample, noise_key,
                    wl_keys, widx, sampling, need_normal, n, wl_boost,
-                   wl_drift, interval_kernel, reduce, tier_shim, mesh):
+                   wl_drift, interval_kernel, reduce, mesh):
     # NB mirrors _sim_synth_jit's donation: wl / sample are shared across
     # dispatches (CRN pairing) and never donated; widx (9) is rebuilt per
     # call and is.
@@ -431,7 +431,7 @@ def _fab_synth_jit(spec, wl, k, mach, caps, keys, sample, noise_key,
             sp, None, None, k, mc, cp, ky, sm, sampling, need_normal,
             wl=w, wl_keys=wk, noise_key=nk, n=n, wl_boost=wl_boost,
             wl_drift=wl_drift, interval_kernel=interval_kernel, reduce=reduce,
-            tier_shim=tier_shim, widx=wi),
+            widx=wi),
         mesh=mesh,
         in_specs=(lane, rep, lane, lane, lane, rep, rep, rep, lane),
         out_specs=_out_specs(reduce), check_vma=False)
@@ -445,7 +445,7 @@ def _plan_padding(B: int, D: int, pad_multiple) -> int:
 
 def sim_trace(spec, trace, oracle_mask, k, mach, caps, keys, sample,
               sampling, need_normal, interval_kernel=True, reduce="stack",
-              tier_shim=False, mesh=None, pad_multiple=None):
+              mesh=None, pad_multiple=None):
     """Trace-mode dispatch, optionally sharded.  Returns ``(out, info)``:
     the raw engine output dict with padded lanes already dropped, and
     the fabric's dispatch info ({} on the plain path)."""
@@ -454,7 +454,7 @@ def sim_trace(spec, trace, oracle_mask, k, mach, caps, keys, sample,
         out = scan_engine._sim_jit(
             spec, trace, oracle_mask, k, mach, caps, keys, sample,
             sampling, need_normal, interval_kernel=interval_kernel,
-            reduce=reduce, tier_shim=tier_shim)
+            reduce=reduce)
         return out, {}
     D = D or 1
     B = keys.shape[0]
@@ -463,14 +463,14 @@ def sim_trace(spec, trace, oracle_mask, k, mach, caps, keys, sample,
         _pad_lanes(x, B, Lp) for x in (spec, mach, caps, keys))
     out = _fab_trace_jit(spec, trace, oracle_mask, k, mach, caps, keys,
                          sample, sampling, need_normal, interval_kernel,
-                         reduce, tier_shim, _lane_mesh(D))
+                         reduce, _lane_mesh(D))
     return _unpad_out(out, B), dict(mesh=D, padded_lanes=Lp)
 
 
 def sim_synth(spec, wl, k, mach, caps, keys, sample, noise_key, wl_keys,
               sampling, need_normal, wl_rep, n, wl_boost=True,
               wl_drift=True, interval_kernel=True, reduce="stack",
-              tier_shim=False, mesh=None, pad_multiple=None):
+              mesh=None, pad_multiple=None):
     """Synth-mode dispatch, optionally sharded (see ``sim_trace``).
 
     ``wl_rep`` maps lane -> workload exactly as in ``_sim_synth_jit``
@@ -483,8 +483,7 @@ def sim_synth(spec, wl, k, mach, caps, keys, sample, noise_key, wl_keys,
         out = scan_engine._sim_synth_jit(
             spec, wl, k, mach, caps, keys, sample, noise_key, wl_keys,
             sampling, need_normal, wl_rep, n, wl_boost=wl_boost,
-            wl_drift=wl_drift, interval_kernel=interval_kernel, reduce=reduce,
-            tier_shim=tier_shim)
+            wl_drift=wl_drift, interval_kernel=interval_kernel, reduce=reduce)
         return out, {}
     D = D or 1
     B = keys.shape[0]
@@ -496,6 +495,5 @@ def sim_synth(spec, wl, k, mach, caps, keys, sample, noise_key, wl_keys,
         _pad_lanes(x, B, Lp) for x in (spec, mach, caps, keys))
     out = _fab_synth_jit(spec, wl, k, mach, caps, keys, sample, noise_key,
                          wl_keys, widx, sampling, need_normal, n, wl_boost,
-                         wl_drift, interval_kernel, reduce, tier_shim,
-                         _lane_mesh(D))
+                         wl_drift, interval_kernel, reduce, _lane_mesh(D))
     return _unpad_out(out, B), dict(mesh=D, padded_lanes=Lp)

@@ -1,8 +1,8 @@
 """Machine registry: one ``machines.get()`` lookup for every call site.
 
 Anywhere the simulator accepts a machine — ``engine.run``,
-``scan_engine.simulate``, ``tuning.tune``, ``experiment.sweep``, the
-benchmarks — it accepts a registry NAME (``"pmem-large"``, ``"numa"``,
+``experiment.sweep``, ``tuning.tune``, ``search.run``, the benchmarks —
+it accepts a registry NAME (``"pmem-large"``, ``"numa"``,
 ``"cxl-1hop"``, ``"dram-cxl-pmem"``), a legacy two-tier ``MachineSpec``,
 or a ``TieredMachineSpec``; resolution happens here instead of each call
 site importing the preset dict.

@@ -79,9 +79,9 @@ def uniform_field(T: int, n: int, seed: int = 0) -> np.ndarray:
 # --------------------------------------------------------------------------
 # Device-resident CRN rows for the trace-synthesis path.
 #
-# Workload-lane sweeps (scan_engine.sweep_workloads / sweep_workload_configs)
-# never build a [T, n] array anywhere: each interval draws ONE uniform row
-# from a counter-based key (fold_in by t — no consumed key chain), shared by
+# Workload-lane sweeps (experiment.sweep(workloads=...)) never build a
+# [T, n] array anywhere: each interval draws ONE uniform row from a
+# counter-based key (fold_in by t — no consumed key chain), shared by
 # every sweep lane, so config comparisons stay paired while per-lane storage
 # stays O(n).  ``synth_noise_field`` reconstructs the same rows host-side so
 # the numpy reference engine can replay a synth run bitwise (tests only —

@@ -1,38 +1,35 @@
-"""Compiled ``lax.scan`` simulation engine + lane-batched sweeps, for EVERY
-policy speaking the functional protocol (baselines/protocol.py).
+"""Compiled ``lax.scan`` simulation engine for EVERY policy speaking the
+functional protocol (baselines/protocol.py).
 
 The numpy engine (engine.py) replays a trace with a Python loop and one
 policy call per interval — fine as a *reference*, but host<->device
-round-trips and per-call dispatch dominate, and tuning studies replay
-dozens of full simulations sequentially.  Here the entire replay — PEBS
-sampling, the policy (via its pure ``observe``/``fires``/``policy``
+round-trips and per-call dispatch dominate.  Here the entire replay —
+PEBS sampling, the policy (via its pure ``observe``/``fires``/``policy``
 functions), engine-side capacity/validity enforcement, the interval cost
 model, and wasteful/recall accounting — is one ``jax.lax.scan`` over
 intervals, compiled once and executed with zero per-interval host syncs.
-On top of it:
 
-  * ``simulate``             — single run of ANY spec, SimResult output;
-  * ``sweep_seeds``          — batched over PRNG keys (sampling-noise
-    study: per-lane noise drawn from keys threaded through the carry);
-  * ``sweep_policy_configs`` — batched over a policy family's knobs: one
-    spec per lane, all lanes sharing one CRN noise field (paired
-    comparisons — config differences are never confounded with noise);
-  * ``arms_sim`` / ``sweep_arms_configs`` — the ARMS-specialized wrappers
-    (the latter precomputes both mode-dependent observation grids once and
-    broadcasts them, so ARMS config lanes pay zero sampling cost);
-  * ``simulate_workload`` / ``sweep_workloads`` / ``sweep_workload_configs``
-    — the trace-SYNTHESIS path: the scan carries ``WorkloadSpec`` state
-    (simulator/workload_spec.py) and synthesizes ``true = work * probs``
-    plus the oracle top-k mask on device each interval; per-lane storage
-    is O(n), nothing ``[T, n]`` exists on host or device.
+What this module holds:
 
-MACHINES are sweep lanes too: every entry point accepts a registry name
-(``machines.get``), a legacy two-tier ``MachineSpec``, or an N-tier
-``TieredMachineSpec`` (simulator/machine_spec.py), and the machine's
-f32 per-tier leaves ride the same lane axis as policy and workload
-knobs — ``experiment.sweep`` flattens a P×W×M×S axis product into ONE
-dispatch of this engine.  The scan carry holds an i32 per-page tier
-index; migrations are adjacent-pair hop chains
+  * ``_simulate`` — the traceable batched replay.  True counts come from
+    a host trace scanned as xs (trace mode) or are synthesized on device
+    from a lane-batched ``WorkloadSpec`` (synth mode: per-lane storage
+    O(n), nothing ``[T, n]`` exists on host or device);
+  * its two jits, ``_sim_jit`` (trace mode) and ``_sim_synth_jit``
+    (synth mode);
+  * the result conversion (``_to_results``: one host transfer per
+    dispatch, then ``_to_result`` per lane) and the dispatch / readback
+    counters (``count_dispatches``, ``count_readbacks``).
+
+Its one caller is ``experiment.sweep``, through ``fabric.sim_trace`` /
+``fabric.sim_synth`` (which add lane sharding and the mixed-family
+union); the sweep assembles the lanes with the helpers here
+(``_stack_specs``, ``_take_lanes``, ``_stack_workloads``,
+``_need_normal``, ``_synth_need_normal``, ``_record_dispatch``).
+
+Machines are sweep lanes too: the machine's f32 per-tier leaves ride the
+same lane axis as policy and workload knobs.  The scan carry holds an
+i32 per-page tier index; migrations are adjacent-pair hop chains
 (``simjax.apply_tier_migrations``) and the interval cost charges each
 tier's bandwidth separately.  N=2 replays are bitwise-identical to the
 historical boolean two-tier engine (tests/test_machine_spec.py).
@@ -51,14 +48,6 @@ Engine-side bookkeeping is shared with the numpy engine via
 (``sample_u``) the two engines agree bitwise on sampling and interval
 arithmetic, so promotions/demotions/wasteful counts match exactly for every
 policy (see tests/test_scan_engine.py).
-
-NOTE on the module boundary: ``simulator/experiment.py`` (the axis-product
-orchestrator) assembles lanes directly on this module's underscore helpers
-(``_sim_jit``/``_sim_synth_jit``, ``_stack_specs``/``_stack_workloads``/
-``_take_lanes``, ``_need_normal``/``_synth_need_normal``, ``_to_results``/
-``_record_dispatch``).  They are a load-bearing
-internal contract shared by exactly those two modules — change their
-signatures in lockstep.
 """
 from __future__ import annotations
 
@@ -70,21 +59,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.baselines.arms_policy import SWEEPABLE, ARMSSpec
-from repro.core.state import ARMSConfig
 from repro.kernels.interval_step import ops as interval_ops
 from repro.simulator import machine_spec, machines, simjax, workload_spec
-from repro.simulator.engine import SimResult, oracle_topk_masks
+from repro.simulator.engine import SimResult
 from repro.simulator.sampling import (_NORMAL_SWITCH, pebs_sample_from_uniform,
-                                      synth_uniform_row, uniform_field)
+                                      synth_uniform_row)
 from repro.utils.topk import top_k
 
-__all__ = [
-    "SWEEPABLE", "simulate", "sweep_seeds", "sweep_policy_configs",
-    "arms_sim", "sweep_arms_configs", "simulate_workload",
-    "sweep_workloads", "sweep_workload_configs", "last_dispatch",
-    "count_dispatches", "count_readbacks", "DispatchCounter",
-]
+__all__ = ["last_dispatch", "count_dispatches", "count_readbacks",
+           "DispatchCounter"]
 
 #: Info about the most recent compiled dispatch (lanes, sampling mode).
 #: The CI quick gates read this to assert tuning and machine sweeps stay
@@ -247,7 +230,7 @@ def _simulate(spec, trace, oracle_mask, k: int, mach, caps, keys, sample,
               noise_key=None, wl_rep: int = 1, n: int | None = None,
               wl_boost: bool = True, wl_drift: bool = True,
               interval_kernel: bool = True, reduce: str = "stack",
-              tier_shim: bool = False, widx=None):
+              widx=None):
     """Traceable batched replay; returns a dict of [B] scalars + timelines.
 
     Lanes (= sweep entries) form the leading axis of every carried array,
@@ -274,10 +257,7 @@ def _simulate(spec, trace, oracle_mask, k: int, mach, caps, keys, sample,
         engine mirrors bitwise;
       * "crn_prng": one uniform row per interval drawn on device from
         ``noise_key`` (counter-based fold_in by t), shared across lanes —
-        CRN pairing without any [T, n] field (synth-mode default);
-      * "pre":  ``sample`` is a [T, P, n] stack of precomputed observation
-        grids (one per period in the family's ``PRE_PERIODS``); lanes only
-        select by ``spec.obs_index(state)``.
+        CRN pairing without any [T, n] field (synth-mode default).
 
     ``interval_kernel`` (static) routes the interval hot path through the
     fused ``kernels/interval_step`` ops — threshold-select oracle masks
@@ -300,10 +280,9 @@ def _simulate(spec, trace, oracle_mask, k: int, mach, caps, keys, sample,
     ``(pages, dst)`` moves via ``tier_policy``, and the engine executes
     them with ``simjax.apply_targeted_migrations`` — up-moves count as
     promotions, down-moves as demotions, sharing the binary path's
-    wasteful accounting.  ``tier_shim`` (static) forces BINARY specs
-    through that same route via the base-class shim; it is bitwise-equal
-    to the default hop-chain path (tests/test_tier_native.py), and exists
-    so tests can assert exactly that.
+    wasteful accounting.  A union spec (fabric.py) is tier-native, so its
+    binary members take this route through the protocol's base shim,
+    bitwise-equal to their own hop-chain path (tests/test_tier_native.py).
     """
     assert reduce in ("stack", "stream")
     if wl is None:
@@ -316,7 +295,7 @@ def _simulate(spec, trace, oracle_mask, k: int, mach, caps, keys, sample,
     pad_p, pad_d = spec.pad_promote(n, k), spec.pad_demote(n, k)
     f32 = jnp.float32
 
-    tn = cls.tier_native or tier_shim
+    tn = cls.tier_native
     vobserve = jax.vmap(cls.observe)
     vfires = jax.vmap(cls.fires)
     vpolicy = jax.vmap(cls.policy, in_axes=(0, 0, 0, 0, None))
@@ -328,9 +307,6 @@ def _simulate(spec, trace, oracle_mask, k: int, mach, caps, keys, sample,
     def observed_for(xs_sample, true_b, state, subs, t0):
         if cls.wants_true_counts:
             return true_b
-        if sampling == "pre":
-            idx = jax.vmap(cls.obs_index)(spec, state)          # [B]
-            return xs_sample[idx]                               # [B, n]
         period = vperiod(spec, state)[:, None]                  # [B, 1]
         if sampling == "prng":
             u = jax.vmap(lambda s: jax.random.uniform(s, (n,), dtype=f32)
@@ -635,52 +611,24 @@ warnings.filterwarnings(
     "ignore", message="Some donated buffers were not usable")
 @functools.partial(
     jax.jit, static_argnames=("k", "sampling", "need_normal",
-                              "interval_kernel", "reduce", "tier_shim"),
-    donate_argnums=(0, 4, 5, 6))
-def _sim_jit(spec, trace, oracle_mask, k, mach, caps, keys, sample,
-             sampling, need_normal, interval_kernel=True, reduce="stack",
-             tier_shim=False):
-    return _simulate(spec, trace, oracle_mask, k, mach, caps, keys, sample,
-                     sampling, need_normal, interval_kernel=interval_kernel,
-                     reduce=reduce, tier_shim=tier_shim)
-
-
-def _precompute_observations(trace, u, periods: tuple, need_normal: bool):
-    """[T, P, n] observation grids for a shared CRN field, one per period.
-
-    Row-by-row scan keeps the transform's intermediates small while
-    producing the full grids every sweep lane shares.
-    """
-    def row(_, xs):
-        u_t, tr_t = xs
-        return None, jnp.stack([
-            pebs_sample_from_uniform(u_t, tr_t, jnp.float32(p),
-                                     need_normal=need_normal)
-            for p in periods])
-    return jax.lax.scan(row, None, (u, trace))[1]
-
-
-@functools.partial(
-    jax.jit, static_argnames=("k", "periods", "need_normal",
                               "interval_kernel", "reduce"),
     donate_argnums=(0, 4, 5, 6))
-def _sim_pre_jit(spec, trace, oracle_mask, k, mach, caps, keys, u, periods,
-                 need_normal, interval_kernel=True, reduce="stack"):
-    obs = _precompute_observations(trace, u, periods, need_normal)
-    return _simulate(spec, trace, oracle_mask, k, mach, caps, keys, obs,
-                     "pre", need_normal, interval_kernel=interval_kernel,
+def _sim_jit(spec, trace, oracle_mask, k, mach, caps, keys, sample,
+             sampling, need_normal, interval_kernel=True, reduce="stack"):
+    return _simulate(spec, trace, oracle_mask, k, mach, caps, keys, sample,
+                     sampling, need_normal, interval_kernel=interval_kernel,
                      reduce=reduce)
 
 
 @functools.partial(
     jax.jit, static_argnames=("k", "sampling", "need_normal",
                               "wl_rep", "n", "wl_boost", "wl_drift",
-                              "interval_kernel", "reduce", "tier_shim"),
+                              "interval_kernel", "reduce"),
     donate_argnums=(0, 3, 4, 5, 7, 8))
 def _sim_synth_jit(spec, wl, k, mach, caps, keys, sample, noise_key,
                    wl_keys, sampling, need_normal, wl_rep, n,
                    wl_boost=True, wl_drift=True, interval_kernel=True,
-                   reduce="stack", tier_shim=False):
+                   reduce="stack"):
     # NB: ``wl`` (position 1) and ``sample`` (6) are NOT donated —
     # experiment.sweep shares one workload stack / CRN field across every
     # per-family dispatch of a single axis-product call.
@@ -688,8 +636,7 @@ def _sim_synth_jit(spec, wl, k, mach, caps, keys, sample, noise_key,
                      sampling, need_normal, wl=wl, wl_keys=wl_keys,
                      noise_key=noise_key, wl_rep=wl_rep, n=n,
                      wl_boost=wl_boost, wl_drift=wl_drift,
-                     interval_kernel=interval_kernel, reduce=reduce,
-                     tier_shim=tier_shim)
+                     interval_kernel=interval_kernel, reduce=reduce)
 
 
 def _synth_need_normal(wl_specs, min_period: float) -> bool:
@@ -760,293 +707,3 @@ def _record_dispatch(**info):
     for ctr in _active_counters:
         ctr.count += 1
         ctr.records.append(dict(info))
-
-
-# ------------------------------------------------------------- public API
-def simulate(spec, trace, machine, k: int, seed: int = 0, sample_u=None,
-             name: str | None = None,
-             use_interval_kernel: bool = True,
-             tier_shim: bool = False) -> SimResult:
-    """Device-resident replay of ``trace`` under any policy spec.
-
-    ``machine``: registry name / MachineSpec / TieredMachineSpec.
-    ``sample_u``: optional [T, n] uniform field selecting the CRN sampling
-    path (pass the same field to ``engine.run(..., sample_u=...)`` for an
-    exactly-comparable reference run).  Default: PEBS noise drawn with
-    ``jax.random`` from a key threaded through the scan carry.
-    ``use_interval_kernel=False`` pins the historical unfused interval
-    path — the fused route is bitwise-equal, so this only matters for
-    equivalence tests and the kernel benchmark.  ``tier_shim=True`` forces
-    a binary spec through the tier-targeted executor via the protocol's
-    shim — also bitwise-equal (tests/test_tier_native.py).
-    """
-    trace = np.asarray(trace)
-    assert 0 < k <= trace.shape[1]
-    oracle = oracle_topk_masks(trace, k)
-    crn = sample_u is not None
-    sample = (jnp.asarray(sample_u, jnp.float32) if crn
-              else jnp.zeros((trace.shape[0], 1), jnp.float32))
-    keys = jax.random.PRNGKey(seed)[None]
-    mach, caps = _mach_lanes(machine, 1, trace.shape[1], k)
-    out = _sim_jit(_lane_specs(spec, 1), jnp.asarray(trace, jnp.float32),
-                   jnp.asarray(oracle), k, mach, caps, keys, sample,
-                   "crn" if crn else "prng",
-                   _need_normal(trace, spec.min_sampling_period()),
-                   interval_kernel=use_interval_kernel,
-                   tier_shim=tier_shim)
-    _record_dispatch(lanes=1, sampling="crn" if crn else "prng",
-                     policy=spec.name, machines=1, T=trace.shape[0],
-                     interval_kernel=use_interval_kernel, reduce="stack")
-    return _to_results(out, [name or spec.name])[0]
-
-
-def sweep_seeds(trace, machine, k: int, seeds, cfg: ARMSConfig | None = None,
-                spec=None) -> list[SimResult]:
-    """Batched runs over PRNG seeds: one compile, one device dispatch.
-
-    Every seed's full replay runs in lockstep in the lane axis — the
-    sampling-noise study (and any seed-averaged comparison) no longer pays
-    one sequential simulation per seed.  Defaults to ARMS (``cfg``); pass
-    any ``spec`` for a baseline.
-    """
-    if spec is None:
-        spec = ARMSSpec.make(base_cfg=cfg)
-    elif cfg is not None:
-        raise ValueError("pass either cfg (ARMS) or spec, not both")
-    seeds = list(seeds)
-    if not seeds:
-        raise ValueError("sweep_seeds needs at least one seed")
-    trace = np.asarray(trace)
-    oracle = oracle_topk_masks(trace, k)
-    keys = jnp.stack([jax.random.PRNGKey(int(s)) for s in seeds])
-    mach, caps = _mach_lanes(machine, len(seeds), trace.shape[1], k)
-    out = _sim_jit(_lane_specs(spec, len(seeds)),
-                   jnp.asarray(trace, jnp.float32), jnp.asarray(oracle), k,
-                   mach, caps, keys,
-                   jnp.zeros((trace.shape[0], 1), jnp.float32), "prng",
-                   _need_normal(trace, spec.min_sampling_period()))
-    _record_dispatch(lanes=len(seeds), sampling="prng", policy=spec.name,
-                     machines=1, T=trace.shape[0], interval_kernel=True,
-                     reduce="stack")
-    return _to_results(out, [f"{spec.name}[seed={s}]" for s in seeds])
-
-
-def sweep_policy_configs(spec_family, trace, machine, k: int, configs,
-                         sim_seed: int = 0, sample_u=None
-                         ) -> list[SimResult]:
-    """Lane-batched sweep over one policy family's knob grid.
-
-    ``spec_family`` is a callable mapping a config dict to a spec (e.g.
-    ``HeMemSpec.make``); ``configs`` a list of config dicts, one lane each.
-    All lanes share ONE common-random-number uniform noise field
-    (``sample_u`` or ``sampling.uniform_field(T, n, seed=sim_seed)``), so
-    config comparisons are paired — never confounded with sampling noise —
-    and the whole sweep is one compiled ``scan``+``vmap`` program.  The
-    numpy engine replaying any one config with the same field produces
-    identical migrations (the tuning-equivalence tests assert this).
-    """
-    configs = list(configs)
-    if not configs:
-        raise ValueError("sweep_policy_configs needs at least one config")
-    specs = [spec_family(**cfg) for cfg in configs]
-    spec = _stack_specs(specs)
-    trace = np.asarray(trace)
-    T, n = trace.shape
-    oracle = oracle_topk_masks(trace, k)
-    if sample_u is None:
-        sample_u = uniform_field(T, n, seed=sim_seed)
-    assert sample_u.shape == (T, n)
-    min_period = min(s.min_sampling_period() for s in specs)
-    keys = jnp.stack([jax.random.PRNGKey(0)] * len(configs))
-    mach, caps = _mach_lanes(machine, len(configs), n, k)
-    out = _sim_jit(spec, jnp.asarray(trace, jnp.float32),
-                   jnp.asarray(oracle), k, mach, caps, keys,
-                   jnp.asarray(sample_u, jnp.float32), "crn",
-                   _need_normal(trace, min_period))
-    _record_dispatch(lanes=len(configs), sampling="crn",
-                     policy=specs[0].name, machines=1, T=T,
-                     interval_kernel=True, reduce="stack")
-    labels = [",".join(f"{nm}={v:.6g}" for nm, v in sorted(cfg.items()))
-              for cfg in configs]
-    return _to_results(out, [f"{specs[0].name}[{lbl}]" for lbl in labels])
-
-
-def arms_sim(trace, machine, k: int, cfg: ARMSConfig | None = None,
-             seed: int = 0, sample_u=None, name: str = "arms") -> SimResult:
-    """ARMS replay of ``trace`` — scan-engine counterpart of
-    ``engine.run(ARMSPolicy(cfg), ...)``."""
-    return simulate(ARMSSpec.make(base_cfg=cfg), trace, machine, k,
-                    seed=seed, sample_u=sample_u, name=name)
-
-
-def sweep_arms_configs(trace, machine, k: int, overrides: dict,
-                       base_cfg: ARMSConfig | None = None, seed: int = 0,
-                       sample_u=None, reduce: str = "stack"
-                       ) -> list[SimResult]:
-    """Batched ARMS runs over a grid of float knob settings.
-
-    ``overrides`` maps ARMSConfig float field names to equal-length value
-    lists; row b of every list forms config b.  All configs share one CRN
-    uniform noise field, which lets the per-mode observation grids
-    (``ARMSSpec.PRE_PERIODS``) be computed once and broadcast across
-    lanes: config lanes pay zero sampling cost, and the whole sweep is one
-    compiled ``scan``+``vmap`` program.  ``reduce="stream"`` drops the
-    ``timeline_*`` stacks for O(lanes) output (scalars are identical) —
-    the search engine's eliminate-and-redraw loops use it.
-    """
-    names = tuple(sorted(overrides))
-    if not names:
-        raise ValueError("overrides must name at least one ARMSConfig knob")
-    B = len(overrides[names[0]])
-    if B == 0 or any(len(overrides[nm]) != B for nm in names):
-        raise ValueError(
-            "override value lists must be non-empty and of equal length; "
-            f"got {({nm: len(overrides[nm]) for nm in names})}")
-    specs = [ARMSSpec.make({nm: overrides[nm][b] for nm in names},
-                           base_cfg=base_cfg) for b in range(B)]
-    spec = _stack_specs(specs)
-    trace = np.asarray(trace)
-    T, n = trace.shape
-    oracle = oracle_topk_masks(trace, k)
-    if sample_u is None:
-        sample_u = uniform_field(T, n, seed=seed)
-    need_normal = _need_normal(trace, specs[0].min_sampling_period())
-    keys = jnp.stack([jax.random.PRNGKey(0)] * B)
-    mach, caps = _mach_lanes(machine, B, n, k)
-    out = _sim_pre_jit(spec, jnp.asarray(trace, jnp.float32),
-                       jnp.asarray(oracle), k, mach, caps, keys,
-                       jnp.asarray(sample_u, jnp.float32),
-                       ARMSSpec.PRE_PERIODS, need_normal, reduce=reduce)
-    _record_dispatch(lanes=B, sampling="pre", policy="arms", machines=1,
-                     T=T, interval_kernel=True, reduce=reduce)
-    labels = [",".join(f"{nm}={float(overrides[nm][b]):.4g}" for nm in names)
-              for b in range(B)]
-    return _to_results(out, [f"arms[{lbl}]" for lbl in labels])
-
-
-# --------------------------------------------- trace synthesis (workloads)
-def simulate_workload(spec, workload, machine, k: int, T: int, n: int,
-                      sim_seed: int = 0, wl_seed: int = 0, sample_u=None,
-                      name: str | None = None,
-                      use_interval_kernel: bool = True) -> SimResult:
-    """Device-synthesized replay of a ``WorkloadSpec`` under any policy.
-
-    The scan engine synthesizes ``true = work * probs`` per interval from
-    the spec's pure ``step`` and computes the oracle mask on device — no
-    [T, n] trace is materialized anywhere (per-lane storage O(n)).  Under
-    the same seeds the run is bitwise-identical to replaying
-    ``workload.materialize(T, n, wl_seed)`` with the
-    ``sampling.synth_noise_field(T, n, sim_seed)`` CRN field (or with
-    ``sample_u`` if given).
-    """
-    assert 0 < k <= n
-    crn = sample_u is not None
-    if crn:
-        sample = jnp.asarray(sample_u, jnp.float32)
-        assert sample.shape == (T, n)
-    else:
-        sample = jnp.zeros((T, 1), jnp.float32)
-    wl = _stack_workloads([workload])
-    mach, caps = _mach_lanes(machine, 1, n, k)
-    out = _sim_synth_jit(
-        _lane_specs(spec, 1), wl, k, mach, caps,
-        jax.random.PRNGKey(0)[None], sample, jax.random.PRNGKey(sim_seed),
-        jax.random.PRNGKey(wl_seed)[None], "crn" if crn else "crn_prng",
-        _synth_need_normal([workload], spec.min_sampling_period()), 1, n,
-        wl_boost=workload.has_boost(), wl_drift=workload.has_drift(),
-        interval_kernel=use_interval_kernel)
-    _record_dispatch(lanes=1, sampling="crn" if crn else "crn_prng",
-                     policy=spec.name, synth=True, workloads=1, configs=1,
-                     machines=1, T=T, interval_kernel=use_interval_kernel,
-                     reduce="stack")
-    label = name or f"{spec.name}@{workload_spec.label_of(workload)}"
-    return _to_results(out, [label])[0]
-
-
-def sweep_workloads(workloads, machine, k: int, T: int, n: int,
-                    cfg: ARMSConfig | None = None, spec=None,
-                    sim_seed: int = 0, wl_seed: int = 0,
-                    names=None) -> list[SimResult]:
-    """One policy across W workload lanes: ONE compiled dispatch.
-
-    ``workloads`` is a list of ``WorkloadSpec``s (combinator outputs
-    welcome; component counts are padded to stack).  Every lane
-    synthesizes its own trace on device and all lanes share the
-    counter-based CRN noise rows, so workload comparisons are paired.
-    Defaults to ARMS (``cfg``); pass any policy ``spec`` for a baseline.
-    """
-    if spec is None:
-        spec = ARMSSpec.make(base_cfg=cfg)
-    elif cfg is not None:
-        raise ValueError("pass either cfg (ARMS) or spec, not both")
-    workloads = list(workloads)
-    if not workloads:
-        raise ValueError("sweep_workloads needs at least one workload")
-    W = len(workloads)
-    names = list(names) if names is not None else [
-        workload_spec.label_of(w, f"wl{i}") for i, w in enumerate(workloads)]
-    mach, caps = _mach_lanes(machine, W, n, k)
-    out = _sim_synth_jit(
-        _lane_specs(spec, W), _stack_workloads(workloads), k, mach, caps,
-        jnp.stack([jax.random.PRNGKey(0)] * W),
-        jnp.zeros((T, 1), jnp.float32), jax.random.PRNGKey(sim_seed),
-        jnp.stack([jax.random.PRNGKey(wl_seed)] * W), "crn_prng",
-        _synth_need_normal(workloads, spec.min_sampling_period()), 1, n,
-        wl_boost=any(w.has_boost() for w in workloads),
-        wl_drift=any(w.has_drift() for w in workloads))
-    _record_dispatch(lanes=W, sampling="crn_prng", policy=spec.name,
-                     synth=True, workloads=W, configs=1, machines=1, T=T,
-                     interval_kernel=True, reduce="stack")
-    return _to_results(out, [f"{spec.name}@{nm}" for nm in names])
-
-
-def sweep_workload_configs(spec_family, configs, workloads, machine, k: int,
-                           T: int, n: int, sim_seed: int = 0,
-                           wl_seed: int = 0, sample_u=None, names=None
-                           ) -> list[list[SimResult]]:
-    """W workloads x B configs as ONE compiled dispatch of W*B lanes.
-
-    Lane ``w * B + b`` scores config ``b`` on workload ``w``; each
-    workload's state is synthesized once per interval and feeds its B
-    config lanes.  All lanes share the CRN noise rows (device
-    counter-based by default; pass ``sample_u`` for an explicit field),
-    so config comparisons stay paired within and across workloads.
-    Returns results grouped per workload: ``out[w][b]``.
-    """
-    configs = list(configs)
-    workloads = list(workloads)
-    if not configs or not workloads:
-        raise ValueError("sweep_workload_configs needs >=1 config and "
-                         ">=1 workload")
-    W, B = len(workloads), len(configs)
-    names = list(names) if names is not None else [
-        workload_spec.label_of(w, f"wl{i}") for i, w in enumerate(workloads)]
-    pol_specs = [spec_family(**cfg) for cfg in configs]
-    lane_spec = _stack_specs([pol_specs[b]
-                              for _ in range(W) for b in range(B)])
-    crn = sample_u is not None
-    if crn:
-        sample = jnp.asarray(sample_u, jnp.float32)
-        assert sample.shape == (T, n)
-    else:
-        sample = jnp.zeros((T, 1), jnp.float32)
-    min_period = min(s.min_sampling_period() for s in pol_specs)
-    mach, caps = _mach_lanes(machine, W * B, n, k)
-    out = _sim_synth_jit(
-        lane_spec, _stack_workloads(workloads), k, mach, caps,
-        jnp.stack([jax.random.PRNGKey(0)] * (W * B)), sample,
-        jax.random.PRNGKey(sim_seed),
-        jnp.stack([jax.random.PRNGKey(wl_seed)] * W),
-        "crn" if crn else "crn_prng",
-        _synth_need_normal(workloads, min_period), B, n,
-        wl_boost=any(w.has_boost() for w in workloads),
-        wl_drift=any(w.has_drift() for w in workloads))
-    _record_dispatch(lanes=W * B, sampling="crn" if crn else "crn_prng",
-                     policy=pol_specs[0].name, synth=True, workloads=W,
-                     configs=B, machines=1, T=T, interval_kernel=True,
-                     reduce="stack")
-    labels = [",".join(f"{nm}={v:.6g}" for nm, v in sorted(cfg.items()))
-              for cfg in configs]
-    res = _to_results(out, [f"{pol_specs[b].name}@{names[w]}[{labels[b]}]"
-                            for w in range(W) for b in range(B)])
-    return [res[w * B:(w + 1) * B] for w in range(W)]

@@ -46,12 +46,6 @@ mode: tune per machine, then cross-evaluate every machine's tuned config
 on every machine in ONE final sweep and report the A->B
 slowdown-vs-native table.
 
-ARMS keeps its precomputed-grid "pre" fast path: trace-mode
-single-machine searches over SWEEPABLE knobs route through
-``scan_engine.sweep_arms_configs`` (observation grids computed once and
-shared by all config lanes) with streaming reduction; machine- or
-workload-lane ARMS searches fall back to the generic CRN sweep.
-
 ``tuning.tune(strategy=...)`` / ``tune_hemem`` / ``tune_arms`` are thin
 views over ``run`` keeping the historical ``(best_cfg, best_res, rows)``
 return shape.
@@ -63,7 +57,7 @@ import math
 
 import numpy as np
 
-from repro.baselines.arms_policy import SWEEPABLE, ARMSSpec
+from repro.baselines.arms_policy import ARMSSpec
 from repro.simulator import experiment, scan_engine
 from repro.simulator import machines as machines_mod
 from repro.simulator.engine import SimResult
@@ -155,21 +149,21 @@ class _EvalCtx:
     Resolves the trace / workload specs / machine lanes once, then scores
     a config population at any horizon as ONE compiled dispatch (per
     policy family): the population rides the policy axis of
-    ``experiment.sweep`` (or the ARMS "pre" sweep), machine or workload
-    lanes ride their own axes, and all lanes share the CRN noise source
-    seeded by ``sim_seed``.  Short horizons slice the trace prefix
-    (trace mode) or scan fewer synthesis intervals of the full-T-resolved
-    workload specs (synth mode — the counter-based CRN rows make short
-    runs exact prefixes of full runs).
+    ``experiment.sweep``, machine or workload lanes ride their own axes,
+    and all lanes share the CRN noise source seeded by ``sim_seed``.
+    Short horizons slice the trace prefix (trace mode) or scan fewer
+    synthesis intervals of the full-T-resolved workload specs (synth
+    mode — the counter-based CRN rows make short runs exact prefixes of
+    full runs).
     """
 
-    def __init__(self, family, make, trace, machine, machines, workloads,
-                 k, T, n, sim_seed, base_cfg, space, mesh=None):
+    def __init__(self, make, trace, machine, machines, workloads, k, T, n,
+                 sim_seed, mesh=None):
         if machines is not None and workloads is not None:
             raise ValueError("machine-lane and workload-lane search modes "
                              "cannot be combined; pass one of them")
-        self.family, self.make, self.k = family, make, k
-        self.sim_seed, self.base_cfg = sim_seed, base_cfg
+        self.make, self.k = make, k
+        self.sim_seed = sim_seed
         self.mesh = mesh
         mach_in = list(machines) if machines is not None else [machine]
         self.machines = [machines_mod.get(m) for m in mach_in]
@@ -197,11 +191,6 @@ class _EvalCtx:
             else:
                 self.group_axis = None
                 self.groups = [None]
-        # ARMS precomputed-grid fast path: per-mode observation grids are
-        # computed once from the CRN field and shared across config lanes.
-        self.use_pre = (family == "arms" and self.trace is not None
-                        and self.group_axis is None
-                        and set(space) <= SWEEPABLE)
 
     def eval(self, configs, horizon: int):
         """Score ``configs`` at ``horizon`` -> (per-group result lists,
@@ -209,35 +198,24 @@ class _EvalCtx:
         policy family (asserted by the CI search gate via the dispatch
         delta)."""
         horizon = int(horizon)
+        specs = [self.make(**cfg) for cfg in configs]
         with scan_engine.count_dispatches() as ctr:
-            if self.use_pre:
-                # precomputed-grid path: single machine, single family, no
-                # lane batch to shard — ``mesh`` intentionally ignored.
-                overrides = {nm: [cfg[nm] for cfg in configs]
-                             for nm in configs[0]}
-                results = scan_engine.sweep_arms_configs(
-                    self.trace[:horizon], self.machines[0], self.k,
-                    overrides, base_cfg=self.base_cfg, seed=self.sim_seed,
-                    reduce="stream")
-                per_group = [results]
+            if self.group_axis == "workload":
+                res = experiment.sweep(
+                    specs, workloads=self.wl_specs,
+                    machines=[self.machines[0]], k=self.k, T=horizon,
+                    n=self.n, sim_seed=self.sim_seed, mesh=self.mesh)
+                per_group = [[res.at(policy=b, workload=g)
+                              for b in range(len(configs))]
+                             for g in range(len(self.groups))]
             else:
-                specs = [self.make(**cfg) for cfg in configs]
-                if self.group_axis == "workload":
-                    res = experiment.sweep(
-                        specs, workloads=self.wl_specs,
-                        machines=[self.machines[0]], k=self.k, T=horizon,
-                        n=self.n, sim_seed=self.sim_seed, mesh=self.mesh)
-                    per_group = [[res.at(policy=b, workload=g)
-                                  for b in range(len(configs))]
-                                 for g in range(len(self.groups))]
-                else:
-                    res = experiment.sweep(
-                        specs, trace=self.trace[:horizon],
-                        machines=self.machines, k=self.k,
-                        sim_seed=self.sim_seed, mesh=self.mesh)
-                    per_group = [[res.at(policy=b, machine=g)
-                                  for b in range(len(configs))]
-                                 for g in range(len(self.groups))]
+                res = experiment.sweep(
+                    specs, trace=self.trace[:horizon],
+                    machines=self.machines, k=self.k,
+                    sim_seed=self.sim_seed, mesh=self.mesh)
+                per_group = [[res.at(policy=b, machine=g)
+                              for b in range(len(configs))]
+                             for g in range(len(self.groups))]
         # ``lanes`` from the dispatch record is LOGICAL (pre-padding), so
         # lane_intervals — and every ASHA/CE compute curve built from it —
         # is identical at any mesh size.
@@ -470,8 +448,7 @@ def run(family: str, strategy: str = "asha", *, trace=None,
 
     ``mesh`` shards each round's lane batch over devices via the sweep
     fabric (experiment.sweep) — results, rankings and lane-interval
-    compute curves are bitwise-identical at any mesh size (the ARMS
-    precomputed-grid fast path has no lane batch and ignores it).
+    compute curves are bitwise-identical at any mesh size.
     """
     from repro.simulator import tuning  # late import: tuning wraps run()
     if strategy not in STRATEGIES:
@@ -491,8 +468,8 @@ def run(family: str, strategy: str = "asha", *, trace=None,
         configs = tuning._sample_grid(space, defaults, budget, search_seed)
     else:
         configs = [dict(c) for c in configs]
-    ctx = _EvalCtx(family, make, trace, machine, machines, workloads, k,
-                   T, n, sim_seed, base_cfg, space, mesh=mesh)
+    ctx = _EvalCtx(make, trace, machine, machines, workloads, k, T, n,
+                   sim_seed, mesh=mesh)
     if strategy == "grid":
         out = _grid(ctx, family, configs)
     elif strategy == "asha":

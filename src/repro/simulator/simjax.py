@@ -10,9 +10,9 @@ them into its scan body.
 
 Since the N-tier machine protocol (simulator/machine_spec.py), placement
 is an i32 per-page **tier index** (0 = fastest) and migrations are
-adjacent-tier-pair hop chains; the two-tier boolean forms survive as thin
-wrappers (``apply_padded_migrations``) whose decisions the tier forms
-reproduce bitwise at N=2.
+adjacent-tier-pair hop chains; the two-tier boolean form survives as
+``apply_padded_migrations`` (run by the serving ``TieredPool``), whose
+decisions the tier forms reproduce bitwise at N=2.
 
 Numerical layout notes (N=2 bitwise equivalence with the pre-N-tier
 engines): tier 0 charges app + migration bytes against one symmetric
@@ -323,15 +323,6 @@ def apply_padded_migrations(in_fast, promote, demote, k: int):
     pexec = p_ok & (rank < room)
     in_fast = in_fast.at[jnp.where(pexec, promote, n)].set(True, mode="drop")
     return in_fast, pexec, dexec
-
-
-def apply_migrations(in_fast, promote, demote, valid, k: int):
-    """Joint-``valid``-mask form (ARMS MigrationPlan layout) of
-    ``apply_padded_migrations``: entries with ``valid[i]`` False are treated
-    as padding in both arrays."""
-    return apply_padded_migrations(
-        in_fast, jnp.where(valid, promote, -1),
-        jnp.where(valid & (demote >= 0), demote, -1), k)
 
 
 def wasteful_update(t, promoted_at, demoted_at, promote, demote, pexec,

@@ -253,9 +253,7 @@ def tune_arms(trace, machine, k, budget: int = 24, search_seed: int = 0,
     """Batched ARMS internal-knob search: one compiled scan per round.
 
     Routed through the unified ``tune(family="arms", ...)`` path, so
-    ASHA/CE work for ARMS knobs too; trace-mode single-machine searches
-    keep the ARMS-specialized sweep (precomputed per-mode observation
-    grids) rather than the generic per-interval CRN transform.
+    ASHA/CE work for ARMS knobs too.
     """
     return tune("arms", trace, machine, k, budget, search_seed, sim_seed,
                 base_cfg=base_cfg, strategy=strategy, **kw)

@@ -12,8 +12,8 @@ policies.  PEBS-style sampling noise is applied separately (sampling.py)
 The compiled scan engine does not need these arrays at all: it
 synthesizes the same counts on device, interval by interval, directly
 from the spec (O(n) per lane instead of O(T*n) — see
-``scan_engine.simulate_workload`` / ``sweep_workloads``), bitwise
-identical to the materialized rows.
+``experiment.sweep(workloads=...)``), bitwise identical to the
+materialized rows.
 
 The set mirrors the paper's workloads: GUPS (dynamic hot set), Silo-YCSB /
 Btree (Zipfian), Silo-TPCC ("latest" distribution), XSBench (small hot set +

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.baselines.arms_policy import ARMSPolicy
+from repro.baselines.arms_policy import ARMSPolicy, ARMSSpec
 from repro.baselines.hemem import HeMemPolicy, HeMemSpec
 from repro.baselines.memtis import MemtisPolicy, MemtisSpec
 from repro.baselines.static import AllSlowPolicy, AllSlowSpec
@@ -139,8 +139,8 @@ class TestLegacyBitwiseEquivalence:
         trace = workloads.make("gups", T=T, n=N)
         u = uniform_field(T, N, seed=7)
         ref = _legacy_crn_run(HeMemPolicy(), trace, PMEM_LARGE, K, u)
-        out = scan_engine.simulate(HeMemSpec.make(), trace, PMEM_LARGE, K,
-                                   sample_u=u)
+        out = experiment.sweep([HeMemSpec.make()], trace=trace,
+                               machines=PMEM_LARGE, k=K, sample_u=u).grid[0]
         assert (out.promotions, out.demotions, out.wasteful) == \
             (ref["promotions"], ref["demotions"], ref["wasteful"])
         np.testing.assert_allclose(out.exec_time_s, ref["exec_time"],
@@ -273,11 +273,9 @@ class TestThreeTier:
         trace = workloads.make("silo-tpcc", T=80, n=N)
         u = uniform_field(80, N, seed=5)
         ref = run(policy_cls(), trace, "dram-cxl-pmem", K, sample_u=u)
-        if make_spec is None:
-            out = scan_engine.arms_sim(trace, "dram-cxl-pmem", K, sample_u=u)
-        else:
-            out = scan_engine.simulate(make_spec(), trace, "dram-cxl-pmem",
-                                       K, sample_u=u)
+        spec = ARMSSpec.make() if make_spec is None else make_spec()
+        out = experiment.sweep([spec], trace=trace, machines="dram-cxl-pmem",
+                               k=K, sample_u=u).grid[0]
         assert (out.promotions, out.demotions, out.wasteful) == \
             (ref.promotions, ref.demotions, ref.wasteful)
         np.testing.assert_allclose(out.exec_time_s, ref.exec_time_s,
@@ -372,7 +370,9 @@ class TestRegistryAndExperiment:
         r1 = run(HeMemPolicy(), trace, "numa", 8)
         r2 = run(HeMemPolicy(), trace, NUMA, 8)
         assert r1.exec_time_s == r2.exec_time_s
-        s1 = scan_engine.simulate(HeMemSpec.make(), trace, "pmem-large", 8)
+        s1 = experiment.sweep([HeMemSpec.make()], trace=trace,
+                              machines="pmem-large", k=8,
+                              seeds=[0, 1]).at(seed=0)
         assert s1.promotions >= 0
         best_cfg, _, _ = tuning.tune("hemem", trace, "pmem-large", 8,
                                      budget=2)
@@ -399,8 +399,9 @@ class TestRegistryAndExperiment:
         res = experiment.sweep(
             [HeMemSpec.make()], workloads=[wl],
             machines=["pmem-large", "numa"], k=16, T=50, n=128, sim_seed=2)
-        single = scan_engine.simulate_workload(
-            HeMemSpec.make(), wl, "numa", 16, 50, 128, sim_seed=2)
+        single = experiment.sweep([HeMemSpec.make()], workloads=[wl],
+                                  machines="numa", k=16, T=50, n=128,
+                                  sim_seed=2).grid[0]
         lane = res.at(machine="numa")
         assert (lane.promotions, lane.demotions, lane.wasteful) == \
             (single.promotions, single.demotions, single.wasteful)

@@ -12,6 +12,7 @@ import pytest
 from repro.baselines.hemem import HeMemSpec
 from repro.simulator import experiment, scan_engine, workload_spec, workloads
 from repro.simulator.machine import PMEM_LARGE
+from repro.simulator.sampling import uniform_field
 
 T, N, K = 6, 256, 32
 MACHS = ["pmem-large", "numa"]
@@ -100,34 +101,39 @@ def _wls():
                                                                   T=T)]
 
 
-HELPERS = {
-    "simulate": lambda: [scan_engine.simulate(
-        HeMemSpec.make(), _trace(), PMEM_LARGE, K, seed=3)],
-    "sweep_seeds": lambda: scan_engine.sweep_seeds(
-        _trace(), PMEM_LARGE, K, [0, 4, 9]),
-    "sweep_policy_configs": lambda: scan_engine.sweep_policy_configs(
-        HeMemSpec.make, _trace(), PMEM_LARGE, K,
-        [dict(hot_threshold=4), dict(hot_threshold=16)]),
-    "sweep_arms_configs": lambda: scan_engine.sweep_arms_configs(
-        _trace(), PMEM_LARGE, K, dict(alpha_s=[0.7, 0.5])),
-    "sweep_arms_configs.stream": lambda: scan_engine.sweep_arms_configs(
-        _trace(), PMEM_LARGE, K, dict(alpha_s=[0.7, 0.5]), reduce="stream"),
-    "simulate_workload": lambda: [scan_engine.simulate_workload(
-        HeMemSpec.make(), _wls()[0], "numa", K, T, N, sim_seed=2)],
-    "sweep_workloads": lambda: scan_engine.sweep_workloads(
-        _wls(), PMEM_LARGE, K, T, N),
-    "sweep_workload_configs": lambda: [
-        r for row in scan_engine.sweep_workload_configs(
-            HeMemSpec.make, [dict(), dict(hot_threshold=4)], _wls(),
-            PMEM_LARGE, K, T, N, sim_seed=5) for r in row],
+#: one sweep per mode: trace or synth; CRN from one seed, per-lane PRNG
+#: from several seeds, or an explicit ``sample_u`` field; streamed or
+#: stacked timelines.  Lanes differ by machine or seed, so names are unique.
+MODES = {
+    "trace.crn": lambda: experiment.sweep(
+        ["hemem"], trace=_trace(), machines=MACHS, k=K),
+    "trace.crn.stack": lambda: experiment.sweep(
+        ["arms"], trace=_trace(), machines=MACHS, k=K, timelines=True),
+    "trace.prng.stack": lambda: experiment.sweep(
+        ["hemem"], trace=_trace(), machines=PMEM_LARGE, seeds=[0, 4, 9],
+        k=K, timelines=True),
+    "trace.sample_u": lambda: experiment.sweep(
+        [HeMemSpec.make(hot_threshold=4)], trace=_trace(), machines=MACHS,
+        k=K, sample_u=uniform_field(T, N, seed=3)),
+    "synth.crn": lambda: experiment.sweep(
+        ["hemem"], workloads=_wls(), machines=PMEM_LARGE, k=K, T=T, n=N),
+    "synth.crn.stack": lambda: experiment.sweep(
+        ["hemem"], workloads=_wls()[:1], machines=MACHS, k=K, T=T, n=N,
+        sim_seed=2, timelines=True),
+    "synth.prng": lambda: experiment.sweep(
+        ["arms"], workloads=_wls(), machines=PMEM_LARGE, seeds=[0, 4],
+        k=K, T=T, n=N),
+    "synth.sample_u.stack": lambda: experiment.sweep(
+        ["hemem"], workloads=_wls(), machines=MACHS, k=K, T=T, n=N,
+        sample_u=uniform_field(T, N, seed=5), timelines=True),
 }
 
 
-@pytest.mark.parametrize("helper", sorted(HELPERS))
-def test_helper_fields_equal_per_lane_device_reads(outputs, helper):
+@pytest.mark.parametrize("mode", sorted(MODES))
+def test_helper_fields_equal_per_lane_device_reads(outputs, mode):
     with scan_engine.count_readbacks() as rb, \
             scan_engine.count_dispatches() as dc:
-        results = HELPERS[helper]()
+        results = MODES[mode]().grid
     assert rb.count == dc.count == len(outputs) == 1
     assert rb.last == {"lanes": len(results)}
     _check_all(results, outputs)

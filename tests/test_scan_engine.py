@@ -18,7 +18,7 @@ from repro.baselines.static import (AllSlowPolicy, AllSlowSpec, OraclePolicy,
                                     OracleSpec)
 from repro.baselines.tpp import TPPPolicy, TPPSpec
 from repro.core.state import ARMSConfig
-from repro.simulator import scan_engine, tuning, workloads
+from repro.simulator import experiment, scan_engine, tuning, workloads
 from repro.simulator.engine import oracle_topk_masks, run
 from repro.simulator.machine import NUMA, PMEM_LARGE
 from repro.simulator.sampling import pebs_sample_from_uniform, uniform_field
@@ -39,7 +39,9 @@ def _crn_pair(wl, machine=PMEM_LARGE, seed=0, cfg=None):
     trace = workloads.make(wl, T=T, n=N)
     u = uniform_field(T, N, seed=123)
     ref = run(ARMSPolicy(cfg), trace, machine, K, seed=seed, sample_u=u)
-    out = scan_engine.arms_sim(trace, machine, K, cfg=cfg, sample_u=u)
+    out = experiment.sweep([ARMSSpec.make(base_cfg=cfg)], trace=trace,
+                           machines=machine, k=K, sample_u=u,
+                           timelines=True).grid[0]
     return ref, out
 
 
@@ -81,8 +83,9 @@ class TestEngineEquivalence:
         trace = workloads.make(wl, T=T, n=N)
         u = uniform_field(T, N, seed=31)
         ref = run(policy_cls(), trace, PMEM_LARGE, K, sample_u=u)
-        out = scan_engine.simulate(make_spec(), trace, PMEM_LARGE, K,
-                                   sample_u=u)
+        out = experiment.sweep([make_spec()], trace=trace,
+                               machines=PMEM_LARGE, k=K, sample_u=u,
+                               timelines=True).grid[0]
         assert (out.promotions, out.demotions, out.wasteful) == \
             (ref.promotions, ref.demotions, ref.wasteful)
         np.testing.assert_allclose(out.exec_time_s, ref.exec_time_s,
@@ -107,10 +110,11 @@ class TestEngineEquivalence:
         """The fused Pallas path and the jnp escape hatch are one formula."""
         u = uniform_field(T, N, seed=9)
         trace = workloads.make("gups", T=T, n=N)
-        a = scan_engine.arms_sim(trace, PMEM_LARGE, K, sample_u=u)
-        b = scan_engine.arms_sim(trace, PMEM_LARGE, K,
-                                 cfg=ARMSConfig(use_score_kernel=False),
-                                 sample_u=u)
+        kw = dict(trace=trace, machines=PMEM_LARGE, k=K, sample_u=u)
+        a = experiment.sweep([ARMSSpec.make()], **kw).grid[0]
+        b = experiment.sweep(
+            [ARMSSpec.make(base_cfg=ARMSConfig(use_score_kernel=False))],
+            **kw).grid[0]
         assert a.promotions == b.promotions
         assert a.wasteful == b.wasteful
         np.testing.assert_allclose(a.exec_time_s, b.exec_time_s, rtol=1e-5)
@@ -118,9 +122,10 @@ class TestEngineEquivalence:
 
 class TestSweeps:
     def test_seed_sweep_deterministic(self):
-        trace = workloads.make("btree", T=T, n=N)
-        r1 = scan_engine.sweep_seeds(trace, PMEM_LARGE, K, [0, 1, 2])
-        r2 = scan_engine.sweep_seeds(trace, PMEM_LARGE, K, [0, 1, 2])
+        kw = dict(trace=workloads.make("btree", T=T, n=N),
+                  machines=PMEM_LARGE, seeds=[0, 1, 2], k=K, timelines=True)
+        r1 = experiment.sweep(["arms"], **kw).grid
+        r2 = experiment.sweep(["arms"], **kw).grid
         for a, b in zip(r1, r2):
             assert a.exec_time_s == b.exec_time_s
             assert a.promotions == b.promotions
@@ -128,50 +133,58 @@ class TestSweeps:
                                           b.timeline_promotions)
 
     def test_seed_sweep_lane_matches_single_run(self):
-        """A sweep lane is bitwise the same replay as a standalone run."""
-        trace = workloads.make("gups", T=T, n=N)
-        single = scan_engine.arms_sim(trace, PMEM_LARGE, K, seed=3)
-        lane = scan_engine.sweep_seeds(trace, PMEM_LARGE, K, [0, 3, 7])[1]
+        """A seed lane is bitwise the same replay whatever lanes share its
+        dispatch: lanes are keyed by their own seed."""
+        kw = dict(trace=workloads.make("gups", T=T, n=N),
+                  machines=PMEM_LARGE, k=K)
+        single = experiment.sweep(["arms"], seeds=[3, 5], **kw).at(seed=0)
+        lane = experiment.sweep(["arms"], seeds=[0, 3, 7], **kw).at(seed=1)
         assert lane.promotions == single.promotions
         assert lane.exec_time_s == single.exec_time_s
 
     def test_seed_sweep_varies_noise(self):
         trace = workloads.make("silo-tpcc", T=T, n=N)
-        rows = scan_engine.sweep_seeds(trace, PMEM_LARGE, K, range(4))
+        rows = experiment.sweep(["arms"], trace=trace, machines=PMEM_LARGE,
+                                seeds=range(4), k=K).grid
         assert len({r.exec_time_s for r in rows}) > 1  # noise does vary
 
     def test_config_sweep_lane_matches_crn_single_run(self):
-        """Config lane 0 (defaults) == arms_sim on the sweep's CRN field."""
+        """Config lane 0 (defaults) == a default-ARMS run on the sweep's
+        CRN field, passed explicitly."""
         seed = 0
         trace = workloads.make("gups", T=T, n=N)
         u = uniform_field(T, N, seed=seed)
-        rows = scan_engine.sweep_arms_configs(
-            trace, PMEM_LARGE, K, dict(alpha_s=[0.7, 0.5]), seed=seed)
-        ref = scan_engine.arms_sim(trace, PMEM_LARGE, K, sample_u=u)
+        rows = experiment.sweep(
+            [ARMSSpec.make(dict(alpha_s=a)) for a in (0.7, 0.5)],
+            trace=trace, machines=PMEM_LARGE, k=K, sim_seed=seed).grid
+        ref = experiment.sweep([ARMSSpec.make()], trace=trace,
+                               machines=PMEM_LARGE, k=K, sample_u=u).grid[0]
         assert rows[0].promotions == ref.promotions
         assert rows[0].exec_time_s == ref.exec_time_s
 
     def test_baseline_seed_sweep_runs_batched(self):
         trace = workloads.make("gups", T=80, n=256)
-        rows = scan_engine.sweep_seeds(trace, PMEM_LARGE, 32, range(3),
-                                       spec=HeMemSpec.make())
+        rows = experiment.sweep([HeMemSpec.make()], trace=trace,
+                                machines=PMEM_LARGE, seeds=range(3),
+                                k=32).grid
         assert len(rows) == 3 and all(r.name.startswith("hemem")
                                       for r in rows)
         assert scan_engine.last_dispatch["lanes"] == 3
 
     def test_config_sweep_differentiates_configs(self):
         trace = workloads.make("gups", T=T, n=N)
-        rows = scan_engine.sweep_arms_configs(
-            trace, PMEM_LARGE, K, dict(access_scale=[10_000.0, 0.0]))
+        rows = experiment.sweep(
+            [ARMSSpec.make(dict(access_scale=a)) for a in (10_000.0, 0.0)],
+            trace=trace, machines=PMEM_LARGE, k=K).grid
         assert rows[0].promotions > 0
         assert rows[1].promotions == 0      # zero benefit -> gate rejects
         assert rows[1].exec_time_s > rows[0].exec_time_s
 
     def test_config_sweep_rejects_non_sweepable(self):
         trace = workloads.make("gups", T=40, n=64)
-        with pytest.raises(ValueError):
-            scan_engine.sweep_arms_configs(trace, PMEM_LARGE, 8,
-                                           dict(bs_max=[32, 64]))
+        with pytest.raises(ValueError, match="non-sweepable"):
+            experiment.sweep([ARMSSpec.make(dict(bs_max=b)) for b in (32, 64)],
+                             trace=trace, machines=PMEM_LARGE, k=8)
 
     def test_tune_arms_runs_batched(self):
         trace = workloads.make("gups", T=80, n=256)

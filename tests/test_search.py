@@ -131,6 +131,31 @@ class TestASHA:
         assert sr.lane_intervals == sum(r.lanes * r.horizon
                                         for r in sr.rounds)
 
+    def test_arms_rows_match_recorded(self):
+        """ARMS scored through the sweep's CRN field: the rows are those
+        ARMS's former precomputed-observation route gave, bit for bit
+        (recorded from it; T=80, n=256, k=32, btree, which switches
+        between ARMS's modes and so between its sampling periods)."""
+        sr = search.run("arms", trace=_trace("btree"), k=K, budget=6,
+                        search_seed=0, sim_seed=0)
+        assert [(r.horizon, len(r.population[None])) for r in sr.rounds] \
+            == [(27, 6), (80, 2)]
+        got = [(c, (r.exec_time_s.hex(), r.promotions, r.demotions,
+                    r.wasteful, r.hot_recall.hex(), r.fast_hit_frac.hex(),
+                    r.mean_slow_bw.hex(), r.mean_fast_hits.hex(),
+                    r.mean_mode.hex(), r.max_promotions_interval))
+               for c, r in sr.rows]
+        assert got == [
+            (dict(alpha_s=0.7, alpha_l=0.1, noise_z=0.25, pht_lambda=0.1),
+             ("0x1.c21da20000000p+2", 65, 33, 4, "0x1.90ccce0000000p-1",
+              "0x1.f7b11c0000000p-2", "0x1.0427740000000p-1",
+              "0x1.f7b10e0000000p-2", "0x1.2666660000000p-2", 30)),
+            (dict(alpha_s=0.6, alpha_l=0.05, noise_z=0.5, pht_lambda=0.2),
+             ("0x1.c2638a0000000p+2", 67, 35, 8, "0x1.93ccce0000000p-1",
+              "0x1.f78df00000000p-2", "0x1.04390c0000000p-1",
+              "0x1.f78de00000000p-2", "0x1.ccccce0000000p-2", 30)),
+        ]
+
     def test_machine_lane_mode(self):
         """machines=[...]: per-machine elimination, each round one
         union-population x M dispatch; every machine gets its own result."""
@@ -180,11 +205,12 @@ class TestCE:
 
     def test_continuous_arms_alphas_leave_the_grid(self):
         """The CE continuous path samples ARMS alphas from a truncated
-        normal — off-grid values — while staying on the precomputed-grid
-        'pre' fast path (alphas are SWEEPABLE batch knobs)."""
+        normal — off-grid values — each round one CRN-paired sweep
+        dispatch (alphas are batched spec leaves)."""
         sr = search.run("arms", "ce", trace=_trace(), k=K, budget=10,
                         ce_rounds=2, search_seed=0, sim_seed=0)
-        assert scan_engine.last_dispatch["sampling"] == "pre"
+        assert all(rec.dispatches == 1 for rec in sr.rounds)
+        assert scan_engine.last_dispatch["sampling"] == "crn"
         drawn = [c for c, _ in sr.rows if c != tuning.ARMS_DEFAULTS]
         assert any(c["alpha_s"] not in tuning.ARMS_SPACE["alpha_s"]
                    for c in drawn)
@@ -246,9 +272,13 @@ class TestTuneViews:
             assert len(rows) <= 4
 
     def test_tune_arms_asha_keeps_pre_path(self):
-        best_cfg, best_res, rows = tuning.tune_arms(
-            _trace(), PMEM_LARGE, K, budget=6, strategy="asha")
-        assert scan_engine.last_dispatch["sampling"] == "pre"
+        """ARMS tunes through the sweep like every family: one CRN-paired
+        dispatch per round (it once had a sampling path of its own)."""
+        with scan_engine.count_dispatches() as ctr:
+            best_cfg, best_res, rows = tuning.tune_arms(
+                _trace(), PMEM_LARGE, K, budget=6, strategy="asha")
+        assert ctr.count >= 2
+        assert all(r["sampling"] == "crn" for r in ctr.records)
         assert set(best_cfg) == set(tuning.ARMS_SPACE)
         assert best_res.exec_time_s == min(r.exec_time_s for _, r in rows)
 

@@ -4,8 +4,9 @@ The PR-8 contract extends the binary promote/demote protocol to
 tier-targeted migrations.  Three guarantees anchor it:
 
   * SHIM EQUIVALENCE — every binary policy routed through the protocol's
-    ``tier_policy`` shim and the tier-targeted executor is BITWISE equal
-    (counts, exec time, timelines) to the historical hop-chain path under
+    ``tier_policy`` shim and the tier-targeted executor (a one-family
+    ``dispatch="union"`` sweep) is BITWISE equal (counts, exec time,
+    timelines) to its own hop-chain path (``dispatch="grouped"``) under
     CRN, on 2- and 3-tier machines alike;
   * EXECUTOR EQUIVALENCE — the compiled targeted executor
     (``simjax.apply_targeted_migrations``) matches the sequential numpy
@@ -59,16 +60,19 @@ def _same_counts(a, b):
 
 
 class TestShimEquivalence:
-    """Binary specs through the tier-targeted executor == hop-chain path."""
+    """Binary specs through the tier-targeted executor == hop-chain path:
+    a one-family union dispatch runs the family through the base shim,
+    the grouped dispatch through its own route."""
 
     @pytest.mark.parametrize("mach", MACHS)
     @pytest.mark.parametrize("fam,mk", BINARY_FAMILIES)
     def test_bitwise_equal_under_crn(self, fam, mk, mach):
         trace = workloads.make("gups", T=T, n=N)
         u = uniform_field(T, N, seed=123)
-        base = scan_engine.simulate(mk(), trace, mach, K, sample_u=u)
-        shim = scan_engine.simulate(mk(), trace, mach, K, sample_u=u,
-                                    tier_shim=True)
+        kw = dict(trace=trace, machines=mach, k=K, sample_u=u,
+                  timelines=True)
+        base = experiment.sweep([mk()], dispatch="grouped", **kw).grid[0]
+        shim = experiment.sweep([mk()], dispatch="union", **kw).grid[0]
         _same_counts(base, shim)
         assert base.exec_time_s == shim.exec_time_s          # bitwise
         assert base.hot_recall == shim.hot_recall
@@ -81,13 +85,12 @@ class TestShimEquivalence:
         # the shim routes around the fused interval kernel as well.
         trace = workloads.make("silo-tpcc", T=T, n=N)
         u = uniform_field(T, N, seed=7)
-        base = scan_engine.simulate(HeMemSpec.make(), trace,
-                                    "dram-cxl-pmem", K, sample_u=u,
-                                    use_interval_kernel=False)
-        shim = scan_engine.simulate(HeMemSpec.make(), trace,
-                                    "dram-cxl-pmem", K, sample_u=u,
-                                    use_interval_kernel=False,
-                                    tier_shim=True)
+        kw = dict(trace=trace, machines="dram-cxl-pmem", k=K, sample_u=u,
+                  use_interval_kernel=False)
+        base = experiment.sweep([HeMemSpec.make()], dispatch="grouped",
+                                **kw).grid[0]
+        shim = experiment.sweep([HeMemSpec.make()], dispatch="union",
+                                **kw).grid[0]
         _same_counts(base, shim)
         assert base.exec_time_s == shim.exec_time_s
 
@@ -190,7 +193,8 @@ class TestTierNativeFamilies:
         u = uniform_field(T, N, seed=123)
         m = machines.get(mach)
         ref = run(pol(), trace, m, K, sample_u=u)
-        out = scan_engine.simulate(mk(), trace, mach, K, sample_u=u)
+        out = experiment.sweep([mk()], trace=trace, machines=mach, k=K,
+                               sample_u=u, timelines=True).grid[0]
         _same_counts(ref, out)
         # exec time and recall accumulate in f32 on device, f64 on host.
         np.testing.assert_allclose(out.exec_time_s, ref.exec_time_s,
@@ -206,7 +210,8 @@ class TestTierNativeFamilies:
         # for hot pages), not sit inert below their thresholds.
         trace = workloads.make("gups", T=T, n=N)
         for _, mk in TIER_FAMILIES:
-            out = scan_engine.simulate(mk(), trace, "pmem-large", K)
+            out = experiment.sweep([mk()], trace=trace, machines="pmem-large",
+                                   k=K, seeds=[0, 1]).at(seed=0)
             assert out.promotions > 0
 
 
@@ -265,8 +270,9 @@ class TestSweepIntegration:
                                    k=K, sample_u=u, dispatch="grouped")
         assert ctr.count == 2
         assert res.axes["policy"] == ["hemem", "jenga"]
-        solo = scan_engine.simulate(JengaSpec.make(), trace,
-                                    "dram-cxl-pmem", K, sample_u=u)
+        solo = experiment.sweep([JengaSpec.make()], trace=trace,
+                                machines="dram-cxl-pmem", k=K,
+                                sample_u=u).grid[0]
         cell = res.at(policy="jenga", machine="dram-cxl-pmem")
         assert cell.promotions == solo.promotions
         assert cell.exec_time_s == solo.exec_time_s

@@ -154,9 +154,9 @@ class TestCombinators:
         """Different component counts pad and sweep in one dispatch."""
         combo = workload_spec.phases([workload_spec.named("gups"),
                                       workload_spec.named("liblinear")], [40])
-        rows = scan_engine.sweep_workloads(
-            [combo, workload_spec.named("btree", T=80)],
-            PMEM_LARGE, K, 80, N)
+        rows = experiment.sweep(
+            ["arms"], workloads=[combo, workload_spec.named("btree", T=80)],
+            machines=PMEM_LARGE, k=K, T=80, n=N).grid
         assert len(rows) == 2
         assert scan_engine.last_dispatch["lanes"] == 2
         assert scan_engine.last_dispatch["synth"] is True
@@ -200,12 +200,13 @@ class TestSynthMaterializeEquivalence:
     def test_bitwise_across_paths(self, name, monkeypatch):
         wl = _workload(name)
         u = synth_noise_field(T, N, seed=7)
-        synth = scan_engine.simulate_workload(
-            HeMemSpec.make(), wl, PMEM_LARGE, K, T, N, sim_seed=7)
+        kw = dict(machines=PMEM_LARGE, k=K, timelines=True)
+        synth = experiment.sweep([HeMemSpec.make()], workloads=[wl], T=T,
+                                 n=N, sim_seed=7, **kw).grid[0]
         trace = wl.materialize(T, N)
         assert trace.dtype == np.float32
-        mat = scan_engine.simulate(HeMemSpec.make(), trace, PMEM_LARGE, K,
-                                   sample_u=u)
+        mat = experiment.sweep([HeMemSpec.make()], trace=trace, sample_u=u,
+                               **kw).grid[0]
         # scan engine: synthesized and materialized replays are BITWISE one
         assert synth.exec_time_s == mat.exec_time_s
         assert (synth.promotions, synth.demotions, synth.wasteful) == \
@@ -236,10 +237,11 @@ class TestSynthMaterializeEquivalence:
     def test_arms_synth_matches_materialized(self):
         wl = workload_spec.named("gups", T=T)
         u = synth_noise_field(T, N, seed=3)
-        synth = scan_engine.simulate_workload(
-            ARMSSpec.make(), wl, PMEM_LARGE, K, T, N, sim_seed=3)
-        mat = scan_engine.simulate(ARMSSpec.make(), wl.materialize(T, N),
-                                   PMEM_LARGE, K, sample_u=u)
+        kw = dict(machines=PMEM_LARGE, k=K, timelines=True)
+        synth = experiment.sweep([ARMSSpec.make()], workloads=[wl], T=T,
+                                 n=N, sim_seed=3, **kw).grid[0]
+        mat = experiment.sweep([ARMSSpec.make()], trace=wl.materialize(T, N),
+                               sample_u=u, **kw).grid[0]
         assert synth.exec_time_s == mat.exec_time_s
         assert (synth.promotions, synth.demotions, synth.wasteful) == \
             (mat.promotions, mat.demotions, mat.wasteful)
@@ -272,23 +274,23 @@ class TestWorkloadSweeps:
         wls = [workload_spec.named("gups", T=T),
                workload_spec.named("silo-tpcc", T=T)]
         cfgs = [dict(hot_threshold=4), dict(hot_threshold=16)]
-        grid = scan_engine.sweep_workload_configs(
-            HeMemSpec.make, cfgs, wls, PMEM_LARGE, K, T, N, sim_seed=5)
+        kw = dict(machines=PMEM_LARGE, k=K, T=T, n=N, sim_seed=5)
+        grid = experiment.sweep([HeMemSpec.make(**c) for c in cfgs],
+                                workloads=wls, **kw)
         assert scan_engine.last_dispatch["lanes"] == 4
         assert scan_engine.last_dispatch["workloads"] == 2
         assert scan_engine.last_dispatch["configs"] == 2
-        single = scan_engine.simulate_workload(
-            HeMemSpec.make(hot_threshold=16), wls[1], PMEM_LARGE, K, T, N,
-            sim_seed=5)
-        lane = grid[1][1]
+        single = experiment.sweep([HeMemSpec.make(hot_threshold=16)],
+                                  workloads=[wls[1]], **kw).grid[0]
+        lane = grid.at(policy=1, workload=1)
         assert lane.exec_time_s == single.exec_time_s
         assert lane.promotions == single.promotions
 
     def test_sweep_never_materializes(self):
         before = workload_spec.MATERIALIZE_CALLS
-        scan_engine.sweep_workload_configs(
-            HeMemSpec.make, [dict(), dict(hot_threshold=4)],
-            [workload_spec.named("gups", T=40)], PMEM_LARGE, 16, 40, 128)
+        experiment.sweep([HeMemSpec.make(), HeMemSpec.make(hot_threshold=4)],
+                         workloads=[workload_spec.named("gups", T=40)],
+                         machines=PMEM_LARGE, k=16, T=40, n=128)
         assert workload_spec.MATERIALIZE_CALLS == before
 
     def test_tune_workload_lanes(self):
